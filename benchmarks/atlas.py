@@ -21,9 +21,11 @@ import json
 import time
 from pathlib import Path
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.atlas import SCENARIOS, report_json, run_atlas
 
 if __name__ == "__main__":
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--scenarios", default=None,
                    help=f"comma-separated subset of {sorted(SCENARIOS)}")

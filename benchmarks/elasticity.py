@@ -44,6 +44,7 @@ import math
 import numpy as np
 
 from repro.cloud import DEFAULT_CATALOG
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.scenario import LoadPhase, Scenario, ScenarioRunner
 from repro.streaming.engine import percentile_sorted
 from repro.workflow import ElasticityConfig, Session, WorkflowConfig
@@ -272,6 +273,7 @@ def main(smoke: bool = False, wall: bool = False, seed: int = 0,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--smoke", action="store_true",
                    help="short CI profile (virtual: <2s wall; wall: ~10s/mode)")

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.analysis.dmd import StreamingDMD
 from repro.analysis.metrics import unit_circle_distance
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.cfd import CFDConfig, init_state, region_fields, step
 from repro.workflow import Session, WorkflowConfig
 
@@ -134,4 +135,5 @@ def main(csv=True):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -24,6 +24,7 @@ from repro.analysis.dmd import StreamingDMD, batched_window_dmd, window_dmd
 from repro.core.records import StreamRecord, encode, decode, encode_batch, \
     decode_batch
 from repro.kernels import ref
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.layers import flash_attention
 
 BENCH_JSON = Path(__file__).resolve().parents[1] / "BENCH_hotpath.json"
@@ -329,6 +330,7 @@ def main(csv=True, only: str | None = None, gate: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--only", default=None,
                    help="comma list of: " + ",".join(SECTIONS))

@@ -30,6 +30,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.clock import VirtualClock
 from repro.workflow import OperatorPipeline, Session, WorkflowConfig
 
@@ -113,6 +114,7 @@ def main(seed: int = 0) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--json", default=str(Path(__file__).resolve().parents[1]

@@ -42,6 +42,7 @@ import time
 from pathlib import Path
 
 from repro.cloud import DEFAULT_CATALOG
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.scenario import LoadPhase, Scenario, run_scenario
 from repro.workflow import ElasticityConfig, WorkflowConfig
 
@@ -169,6 +170,7 @@ def main(seeds: list[int], trace_path: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--seeds", default="0,1,2",
                    help="comma-separated VirtualClock seeds")

@@ -30,6 +30,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.scenario import Fault, LoadPhase, Scenario, run_scenario
 from repro.streaming.operators import OperatorPipeline
 from repro.workflow import ElasticityConfig, WorkflowConfig
@@ -131,6 +132,7 @@ def main(seeds: list[int], trace_path: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--seeds", default="0,1,2",
                    help="comma-separated VirtualClock seeds")

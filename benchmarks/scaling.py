@@ -11,6 +11,7 @@ import time
 
 from repro.analysis.dmd import StreamingDMD
 from repro.analysis.metrics import unit_circle_distance
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.synthetic import GeneratorConfig, SyntheticGenerator
 from repro.workflow import Session, WorkflowConfig
 
@@ -76,4 +77,5 @@ def main(csv=True):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

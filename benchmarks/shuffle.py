@@ -45,6 +45,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.scenario import LoadPhase, Scenario, run_scenario
 from repro.streaming.operators import OperatorPipeline
 from repro.workflow import ElasticityConfig, WorkflowConfig
@@ -213,6 +214,7 @@ def main(seeds: list[int], n_ranks: int,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--seeds", default="0",
                    help="comma-separated VirtualClock seeds")

@@ -42,6 +42,7 @@ import json
 import time
 from pathlib import Path
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.scenario import LoadPhase, Scenario, TenantTraffic, run_scenario
 from repro.tenancy import TenantSpec
 from repro.workflow import ElasticityConfig, WorkflowConfig
@@ -156,6 +157,7 @@ def main(seeds: list[int], trace_path: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--seeds", default="0",
                    help="comma-separated VirtualClock seeds")

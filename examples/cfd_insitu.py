@@ -4,6 +4,7 @@ stability panel (paper Figs 4/5) — on the declarative Session API.
 
     PYTHONPATH=src python examples/cfd_insitu.py
 """
+import sys
 import time
 
 import numpy as np
@@ -68,6 +69,12 @@ print(f"\nsimulation: {N_STEPS} steps in {sim_t:.2f}s "
 print(f"broker: {stats.sent} records sent in {stats.frames_sent} frames, "
       f"{stats.dropped} dropped, "
       f"{stats.bytes_sent/1e6:.2f} MB on the wire")
+# the engine turns an analysis exception into a Result value: count them,
+# or a broken analysis would print an empty panel and exit 0
+failed = [r for r in session.results() if isinstance(r.value, Exception)]
+print(f"failed analyses: {len(failed)}")
+for r in failed[:3]:
+    print(f"  {r.stream_key}: {r.value!r}")
 
 print("\nper-region flow stability (paper Fig 5; 0 = neutrally stable):")
 latest = session.exec_plan.latest("stability_panel")
@@ -79,3 +86,5 @@ for key in sorted(latest, key=lambda k: int(k.split("/r")[-1])):
           f"{v:9.6f} {bar}")
 print("\nlower slabs (building wakes) should be less stable than the "
       "free stream above — that is the paper's Fig-5 insight.")
+if failed:
+    sys.exit(1)

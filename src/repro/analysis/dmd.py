@@ -1,23 +1,34 @@
 """Dynamic Mode Decomposition in JAX — the paper's Cloud-side analysis.
 
-Three implementations:
+Three entry points, one eigensolve:
 
 * ``exact_dmd`` — PyDMD-equivalent batch DMD on a snapshot window
-  (SVD -> low-rank operator -> eigenvalues), jitted.
+  (thin SVD -> rank-r reduced operator -> eigenvalues).
 * ``window_dmd`` / ``batched_window_dmd`` — the stream-operator entry
-  points.  Both route through the *method-of-snapshots* solve
-  ``_masked_window_eigs``: eigenvalues come from the (m, m) snapshot Gram
-  matrix instead of the (d, m) SVD, so a window of d=512 features costs one
-  ``(d, m)·(d, m)`` einsum plus small-matrix eigendecompositions.  Because
-  validity is a mask rather than a shape, panes are zero-padded to
+  points.  Both route through the *method-of-snapshots* reduction
+  ``_masked_window_operator``: the reduced operator comes from the (m, m)
+  snapshot Gram matrix instead of the (d, m) SVD, so a window of d=512
+  features costs one ``(d, m)·(d, m)`` einsum plus a small ``eigh``.
+  Because validity is a mask rather than a shape, panes are zero-padded to
   power-of-two buckets (features, snapshots, and — for the batched entry —
   pane count), the jit cache stays O(log) across ragged windows, and
-  ``batched_window_dmd`` vmaps the whole solve across co-fired panes in a
-  single device dispatch.
+  ``batched_window_dmd`` vmaps the whole reduction across co-fired panes in
+  a single device dispatch.
 * ``StreamingDMD`` — online DMD over unbounded streams: Gram updates
   G += XᵀX, A += YᵀX over snapshot-pair blocks, eigenvalues from the
-  Gram-space operator.  This is what each stream's executor runs per
-  micro-batch.
+  Gram-space operator (``gram_eigs``).  This is what each stream's executor
+  runs per micro-batch.
+
+Every path splits the same way.  The device does the heavy work — Gram
+products, the thin SVD or ``eigh``, and the projection down to a rank-r
+reduced operator (r <= ``rank``, a ``(k, r, r)`` stack for batched panes)
+— and ``_small_eigs`` takes the eigenvalues of those r×r operators on the
+host with ``np.linalg.eigvals``: a nonsymmetric eigensolve has no TPU
+lowering, and 64 floats per pane is all that crosses back.  The same path
+runs on every backend, so CPU tests cover the code the chip runs.  The
+device matmuls run at full f32 precision (``_PRECISION``): TPU's default
+single bf16 pass would bury every direction below ~1e-2 of the leading
+singular value, and the Gram route squares that ratio.
 
 ``StreamingDMD`` is **device-resident**: G and A live as ``jax.Array`` and
 never round-trip through the host between updates.  The batched entry point
@@ -43,24 +54,66 @@ import jax.numpy as jnp
 import numpy as np
 
 F32 = jnp.float32
+_PRECISION = "highest"       # full-f32 device matmuls (see module docstring)
+# Every route keeps a direction only if its squared singular value s² (a
+# Gram eigenvalue) exceeds this fraction of the largest.  In f32 a Gram
+# eigenvalue carries an absolute error of a few eps·s0² (more after long
+# accumulations), so a direction near 1e-5 is known only to tens of
+# percent — and a badly resolved direction in the reduced operator
+# perturbs every eigenvalue, the leading one included.  Above 1e-4 the
+# CFD slab spectra (fast-decaying) stay within ~1e-3 of a float64 SVD
+# reference; at 1e-5 they were off by up to 2.5e-2.  Directions below it
+# are also under the int8 wire codec's noise floor (~2e-3 of a block's
+# largest value).
+_REL_TOL = 1e-4
+
+
+def _small_eigs(M, n_good) -> np.ndarray:
+    """The one DMD eigensolve: eigenvalues of rank-r reduced operators.
+
+    ``M``: (..., r, r) operators from a device reduction; ``n_good``: (...)
+    count of trustworthy directions in each.  Bad directions arrive as zero
+    columns of M (block-triangular), so they contribute exact-zero
+    eigenvalues; after the magnitude-descending sort they sit last and are
+    masked to NaN, which consumers filter.  Runs on the host with
+    ``np.linalg.eigvals`` — JAX has no TPU lowering for a nonsymmetric
+    eigensolve — and returns complex64.  An operator with a non-finite
+    entry yields all-NaN eigenvalues instead of raising."""
+    M = np.asarray(M, np.float64)
+    n_good = np.asarray(n_good)
+    finite = np.isfinite(M).all(axis=(-2, -1))
+    eigs = np.linalg.eigvals(np.where(finite[..., None, None], M, 0.0))
+    order = np.argsort(-np.abs(eigs), axis=-1, kind="stable")
+    eigs = np.take_along_axis(eigs, order, axis=-1)
+    keep = (np.arange(M.shape[-1]) < n_good[..., None]) & finite[..., None]
+    return np.where(keep, eigs, np.nan).astype(np.complex64)
 
 
 @partial(jax.jit, static_argnames=("rank",))
-def exact_dmd(snapshots: jax.Array, rank: int = 8):
+def _exact_operator(snapshots: jax.Array, rank: int):
+    """Device half of ``exact_dmd``: (A~ (r, r), #good directions, energy)."""
+    with jax.default_matmul_precision(_PRECISION):
+        X = snapshots[:, :-1].astype(F32)
+        Y = snapshots[:, 1:].astype(F32)
+        U, S, Vt = jnp.linalg.svd(X, full_matrices=False)
+        r = min(rank, S.shape[0])
+        good = S[:r] ** 2 > _REL_TOL * jnp.maximum(S[0] ** 2, 1e-30)
+        Sinv = jnp.where(good, 1.0 / S[:r], 0.0)
+        Atilde = U[:, :r].T @ Y @ Vt[:r].T * Sinv[None, :]
+        energy = jnp.sum(S[:r] ** 2) / jnp.maximum(jnp.sum(S ** 2), 1e-30)
+    return Atilde, jnp.sum(good), energy
+
+
+def exact_dmd(snapshots, rank: int = 8) -> tuple[np.ndarray, float]:
     """snapshots: (n_features, n_steps).  Returns (eigenvalues, energy).
 
     X = snaps[:, :-1], Y = snaps[:, 1:];  A~ = Uᵀ Y V S⁻¹ (rank-truncated).
+    Eigenvalues come magnitude-descending; directions below ``_REL_TOL``
+    are NaN.
     """
-    X = snapshots[:, :-1].astype(F32)
-    Y = snapshots[:, 1:].astype(F32)
-    U, S, Vt = jnp.linalg.svd(X, full_matrices=False)
-    r = min(rank, S.shape[0])
-    U, S, Vt = U[:, :r], S[:r], Vt[:r]
-    Sinv = jnp.where(S > 1e-10, 1.0 / S, 0.0)
-    Atilde = U.T @ Y @ Vt.T * Sinv[None, :]
-    eigs = jnp.linalg.eigvals(Atilde)
-    energy = jnp.sum(S[:r] ** 2) / jnp.maximum(jnp.sum(S ** 2), 1e-30)
-    return eigs, energy
+    Atilde, n_good, energy = _exact_operator(jnp.asarray(snapshots),
+                                             rank=rank)
+    return _small_eigs(Atilde, n_good), float(energy)
 
 
 @jax.jit
@@ -76,7 +129,8 @@ def _gram_pair_raw(G: jax.Array, A: jax.Array, X: jax.Array, Y: jax.Array):
     (kernels/gram.py) and its allclose oracle.  All-zero padding rows are
     no-ops in both products, so callers may pad n freely."""
     Xf, Yf = X.astype(F32), Y.astype(F32)
-    return G + Xf.T @ Xf, A + Yf.T @ Xf
+    with jax.default_matmul_precision(_PRECISION):
+        return G + Xf.T @ Xf, A + Yf.T @ Xf
 
 
 gram_pair_update = jax.jit(_gram_pair_raw)
@@ -88,25 +142,38 @@ gram_pair_update_donated = jax.jit(_gram_pair_raw, donate_argnums=(0, 1))
 
 
 @partial(jax.jit, static_argnames=("rank",))
+def _gram_operator(G: jax.Array, A: jax.Array, rank: int,
+                   rel_tol=_REL_TOL):
+    """Device half of ``gram_eigs``: (M_r (r, r), #good directions)."""
+    with jax.default_matmul_precision(_PRECISION):
+        s, U = jnp.linalg.eigh(G)                # ascending
+        # one subspace-iteration + Rayleigh-Ritz step on the top 2·rank
+        # directions: TPU's eigh leaves eigenvalue errors of ~1e-5·‖G‖,
+        # which a direction at _REL_TOL cannot afford; the small eigh of
+        # QᵀGQ resolves it to the f32 rounding of G itself
+        p = min(2 * rank, G.shape[0])
+        Q, _ = jnp.linalg.qr(G @ U[:, -p:])
+        s, W = jnp.linalg.eigh(Q.T @ G @ Q)
+        s = s[::-1]
+        U = (Q @ W)[:, ::-1]
+        r = min(rank, G.shape[0])
+        s_r, U_r = s[:r], U[:, :r]
+        good = s_r > rel_tol * jnp.maximum(s_r[0], 1e-30)
+        inv = jnp.where(good, 1.0 / jnp.maximum(s_r, 1e-30), 0.0)
+        M = (U_r.T @ A @ U_r) * inv[None, :]
+    return M, jnp.sum(good)
+
+
 def gram_eigs(G: jax.Array, A: jax.Array, rank: int = 8,
-              rel_tol: float = 1e-7):
+              rel_tol: float = _REL_TOL) -> np.ndarray:
     """Eigenvalues of the online-DMD operator, rank-truncated.
 
     G = X Xᵀ (PSD), A = Y Xᵀ.  Project onto G's dominant eigenspace U_r
     (anything else is noise-nullspace and would blow up the pseudo-inverse):
-    M_r = U_rᵀ A U_r diag(1/s_r);  eig(M_r)."""
-    s, U = jnp.linalg.eigh(G)                    # ascending
-    s = s[::-1]
-    U = U[:, ::-1]
-    r = min(rank, G.shape[0])
-    s_r, U_r = s[:r], U[:, :r]
-    good = s_r > rel_tol * jnp.maximum(s_r[0], 1e-30)
-    inv = jnp.where(good, 1.0 / jnp.maximum(s_r, 1e-30), 0.0)
-    M = (U_r.T @ A @ U_r) * inv[None, :]
-    eigs = jnp.linalg.eigvals(M)
-    # null directions are padded with NaN — consumers (metrics, tests) filter
-    # non-finite entries, so rank padding never reads as (in)stability
-    return jnp.where(good, eigs, jnp.nan + 0.0j)
+    M_r = U_rᵀ A U_r diag(1/s_r);  eig(M_r).  Null directions come back NaN
+    — consumers (metrics, tests) filter non-finite entries, so rank padding
+    never reads as (in)stability."""
+    return _small_eigs(*_gram_operator(G, A, rank=rank, rel_tol=rel_tol))
 
 
 def _pad_rows(n: int) -> int:
@@ -121,19 +188,17 @@ def _pad_cols(n: int, minimum: int = 4) -> int:
     return max(minimum, _pad_rows(n))
 
 
-def _masked_window_eigs(snaps: jax.Array, n_valid: jax.Array,
-                        rank: int, rel_tol: float = 1e-5):
-    """Windowed DMD on a zero-padded (d, m) pane, method of snapshots.
+def _masked_window_operator(snaps: jax.Array, n_valid: jax.Array,
+                            rank: int, rel_tol: float = _REL_TOL):
+    """Windowed DMD reduction on a zero-padded (d, m) pane, method of
+    snapshots.  Returns (M (r, r), #good directions) for ``_small_eigs``.
 
     ``snaps`` holds ``n_valid`` real snapshot columns followed by zero
     padding; ``rank``/shapes are static, ``n_valid`` is data, so one
     compiled variant serves every pane in the same (d, m) bucket and the
     whole thing vmaps across panes.
 
-    ``rel_tol`` applies to s² (the Gram eigenvalues): 1e-5 relative sits
-    safely above the f32 ``eigh`` noise floor (~machine-eps relative, so a
-    rank-deficient pane's junk directions straddle a 1e-7 cutoff and would
-    leak spurious near-zero eigenvalues into the spectrum).
+    ``rel_tol`` applies to s² (the Gram eigenvalues); see ``_REL_TOL``.
 
     Exactness: with X = snaps[:, :n-1], Y = snaps[:, 1:n], exact DMD's
     reduced operator is A~ = Uᵀ Y V S⁻¹ with X = U S Vᵀ.  Substituting
@@ -144,44 +209,43 @@ def _masked_window_eigs(snaps: jax.Array, n_valid: jax.Array,
     padded position that holds real data) out of both Grams.  Spurious
     directions (beyond the pane's true pair count or below ``rel_tol``)
     are zeroed out of the operator — block-triangular, so they contribute
-    exact-zero eigenvalues — then the magnitude-descending sort pushes
-    them last and they are masked to NaN, which consumers already filter.
+    exact-zero eigenvalues — which ``_small_eigs`` sorts last and masks to
+    NaN, which consumers already filter.
     """
-    m = snaps.shape[1]
-    P = snaps.T @ snaps                           # (m, m) snapshot Gram
-    lane = jnp.arange(m - 1)
-    colmask = (lane < n_valid - 1).astype(F32)    # valid X columns
-    mm = colmask[:, None] * colmask[None, :]
-    G = P[:-1, :-1] * mm                          # XᵀX
-    C = P[:-1, 1:] * mm                           # XᵀY
-    s2, V = jnp.linalg.eigh(G)                    # ascending
-    r = min(rank, m - 1)
-    s2_r = s2[-r:][::-1]                          # top-r, descending
-    V_r = V[:, -r:][:, ::-1]
-    good = ((jnp.arange(r) < n_valid - 1)
-            & (s2_r > rel_tol * jnp.maximum(s2_r[0], 1e-30)))
-    sinv = jnp.where(good, 1.0 / jnp.sqrt(jnp.maximum(s2_r, 1e-30)), 0.0)
-    M = (V_r.T @ C @ V_r) * (sinv[:, None] * sinv[None, :])
-    gm = good.astype(F32)
-    M = M * (gm[:, None] * gm[None, :])
-    eigs = jnp.linalg.eigvals(M)
-    eigs = eigs[jnp.argsort(-jnp.abs(eigs))]
-    return jnp.where(jnp.arange(r) < jnp.sum(good), eigs, jnp.nan + 0.0j)
+    with jax.default_matmul_precision(_PRECISION):
+        m = snaps.shape[1]
+        P = snaps.T @ snaps                       # (m, m) snapshot Gram
+        lane = jnp.arange(m - 1)
+        colmask = (lane < n_valid - 1).astype(F32)   # valid X columns
+        mm = colmask[:, None] * colmask[None, :]
+        G = P[:-1, :-1] * mm                      # XᵀX
+        C = P[:-1, 1:] * mm                       # XᵀY
+        s2, V = jnp.linalg.eigh(G)                # ascending
+        r = min(rank, m - 1)
+        s2_r = s2[-r:][::-1]                      # top-r, descending
+        V_r = V[:, -r:][:, ::-1]
+        good = ((jnp.arange(r) < n_valid - 1)
+                & (s2_r > rel_tol * jnp.maximum(s2_r[0], 1e-30)))
+        sinv = jnp.where(good, 1.0 / jnp.sqrt(jnp.maximum(s2_r, 1e-30)), 0.0)
+        M = (V_r.T @ C @ V_r) * (sinv[:, None] * sinv[None, :])
+        gm = good.astype(F32)
+        M = M * (gm[:, None] * gm[None, :])
+    return M, jnp.sum(good)
 
 
-_window_solve = jax.jit(_masked_window_eigs, static_argnames=("rank",))
+_window_operator = jax.jit(_masked_window_operator, static_argnames=("rank",))
 
-# one vmapped+jitted solver per rank (rank is a config constant in
+# one vmapped+jitted reduction per rank (rank is a config constant in
 # practice, so this dict stays O(1); the jit cache under each entry stays
 # O(log) thanks to power-of-two (k, d, m) bucketing by the callers)
-_BATCH_SOLVERS: dict[int, object] = {}
+_BATCH_OPERATORS: dict[int, object] = {}
 
 
-def _batched_solver(rank: int):
-    fn = _BATCH_SOLVERS.get(rank)
+def _batched_operator(rank: int):
+    fn = _BATCH_OPERATORS.get(rank)
     if fn is None:
-        fn = jax.jit(jax.vmap(partial(_masked_window_eigs, rank=rank)))
-        _BATCH_SOLVERS[rank] = fn
+        fn = jax.jit(jax.vmap(partial(_masked_window_operator, rank=rank)))
+        _BATCH_OPERATORS[rank] = fn
     return fn
 
 
@@ -220,8 +284,8 @@ def window_dmd(snapshots, rank: int = 8,
     m = len(rows)
     pane = np.zeros((_pad_rows(max(d, 1)), _pad_cols(m)), np.float32)
     _fill_pane(pane, rows, d)
-    eigs = _window_solve(jnp.asarray(pane), jnp.int32(m), rank=rank)
-    return np.asarray(eigs)
+    return _small_eigs(*_window_operator(jnp.asarray(pane), jnp.int32(m),
+                                         rank=rank))
 
 
 def batched_window_dmd(panes, rank: int = 8,
@@ -230,7 +294,7 @@ def batched_window_dmd(panes, rank: int = 8,
 
     ``panes``: sequence of snapshot iterables (one fired pane per key /
     stream).  Panes are zero-padded into power-of-two (k, d, m) buckets and
-    each bucket goes through one vmapped ``_masked_window_eigs`` call —
+    each bucket goes through one vmapped ``_masked_window_operator`` call —
     k ragged panes cost O(distinct m-buckets) dispatches instead of k.
     Returns one eigenvalue array per pane, in input order; panes shorter
     than 3 snapshots get the zero sentinel, padding slots inside a bucket
@@ -258,7 +322,7 @@ def batched_window_dmd(panes, rank: int = 8,
         else:
             grouped.append((mp, list(buckets[mp])))
     dp = _pad_rows(max(d, 1))
-    solver = _batched_solver(rank)
+    solver = _batched_operator(rank)
     pending = []                          # dispatch all, then sync once
     for mp, idxs in grouped:
         kp = _pad_rows(len(idxs))
@@ -268,8 +332,8 @@ def batched_window_dmd(panes, rank: int = 8,
             _fill_pane(slab[slot], pane_rows[i], d)
             nv[slot] = len(pane_rows[i])
         pending.append((idxs, solver(jnp.asarray(slab), jnp.asarray(nv))))
-    for idxs, dev_eigs in pending:
-        eigs = np.asarray(dev_eigs)
+    for idxs, (M, n_good) in pending:
+        eigs = _small_eigs(M, n_good)
         for slot, i in enumerate(idxs):
             out[i] = eigs[slot]
     return out   # type: ignore[return-value]
@@ -407,14 +471,12 @@ class StreamingDMD:
             snaps = jnp.asarray(np.stack(self._buf, axis=1))
             self.h2d_transfers += 1
             self.device_calls += 1
-            e, _ = exact_dmd(snaps, rank=self.rank)
+            eigs, _ = exact_dmd(snaps, rank=self.rank)
             self.d2h_transfers += 1
-            eigs = np.asarray(e)
         else:
             self.device_calls += 1
-            e = gram_eigs(self._G, self._A, rank=self.rank)
+            eigs = gram_eigs(self._G, self._A, rank=self.rank)
             self.d2h_transfers += 1
-            eigs = np.asarray(e)
         self._eigs_cache = eigs
         self._eigs_seen = self.n_seen
         return eigs

@@ -42,19 +42,32 @@ CPU fallback.
 from __future__ import annotations
 
 import struct
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any
 
 import msgpack
 import numpy as np
+import zstandard as zstd
 
-try:
-    import zstandard as zstd
-    _ZSTD_C = zstd.ZstdCompressor(level=1)
-    _ZSTD_D = zstd.ZstdDecompressor()
-except Exception:  # pragma: no cover
-    zstd = None
+# zstd (de)compressor objects are not thread-safe, and every broker sender
+# and endpoint is its own thread: one pair per thread, built on first use
+_ZSTD = threading.local()
+
+
+def _zstd_compress(blob: bytes) -> bytes:
+    c = getattr(_ZSTD, "c", None)
+    if c is None:
+        c = _ZSTD.c = zstd.ZstdCompressor(level=1)
+    return c.compress(blob)
+
+
+def _zstd_decompress(blob: bytes) -> bytes:
+    d = getattr(_ZSTD, "d", None)
+    if d is None:
+        d = _ZSTD.d = zstd.ZstdDecompressor()
+    return d.decompress(blob)
 
 QBLOCK = 256
 # scale = max|block| * (1/127), as an explicit f32 multiply: XLA rewrites
@@ -257,15 +270,15 @@ def encode(rec: StreamRecord, *, compress: str = "zstd") -> bytes:
         # frames stay byte-identical with pre-tenancy peers
         msg["u"] = rec.tenant
     blob = msgpack.packb(msg, use_bin_type=True)
-    if compress.endswith("zstd") and zstd is not None:
-        return b"Z" + _ZSTD_C.compress(blob)
+    if compress.endswith("zstd"):
+        return b"Z" + _zstd_compress(blob)
     return b"M" + blob
 
 
 def decode(data: bytes) -> StreamRecord:
     tag, blob = data[:1], data[1:]
     if tag == b"Z":
-        blob = _ZSTD_D.decompress(blob)
+        blob = _zstd_decompress(blob)
     msg = msgpack.unpackb(blob, raw=False)
     if msg["e"] == "int8":
         payload = dequantize_int8(msg["p"])
@@ -369,15 +382,15 @@ def encode_batch(recs: list[StreamRecord], *, compress: str = "zstd",
         # for default-only batches (frame bytes unchanged vs. pre-tenancy)
         msg["u"] = _pack_col([r.tenant for r in recs])
     blob = msgpack.packb(msg, use_bin_type=True)
-    if compress.endswith("zstd") and zstd is not None:
-        return b"C" + _ZSTD_C.compress(blob)
+    if compress.endswith("zstd"):
+        return b"C" + _zstd_compress(blob)
     return b"B" + blob
 
 
 def decode_batch(data: bytes) -> list[StreamRecord]:
     tag, blob = data[:1], data[1:]
     if tag == b"C":
-        blob = _ZSTD_D.decompress(blob)
+        blob = _zstd_decompress(blob)
     msg = msgpack.unpackb(blob, raw=False)
     n = msg["n"]
     per_stream = msg["e"] == "int8s"

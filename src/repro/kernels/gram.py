@@ -26,6 +26,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
+# full-f32 MXU products: the Gram route squares the snapshots' condition
+# number, so a single bf16 pass would bury every direction below ~1e-2
+# of the leading singular value (analysis/dmd.py)
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _gram_kernel(xi_ref, xj_ref, g_ref, out_ref, acc_scr, *, n_n: int):
@@ -38,7 +42,8 @@ def _gram_kernel(xi_ref, xj_ref, g_ref, out_ref, acc_scr, *, n_n: int):
     xi = xi_ref[...].astype(F32)                       # (bn, bd)
     xj = xj_ref[...].astype(F32)                       # (bn, bd)
     acc_scr[...] += jax.lax.dot_general(
-        xi, xj, (((0,), (0,)), ((), ())), preferred_element_type=F32)
+        xi, xj, (((0,), (0,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=F32)
 
     @pl.when(ni == n_n - 1)
     def _finish():
@@ -88,8 +93,10 @@ def _gram_pair_kernel(xi_ref, xj_ref, yi_ref, g_ref, a_ref, g_out, a_out,
     xj = xj_ref[...].astype(F32)                       # (bn, bd) X cols-j
     yi = yi_ref[...].astype(F32)                       # (bn, bd) Y cols-i
     dims = (((0,), (0,)), ((), ()))
-    g_acc[...] += jax.lax.dot_general(xi, xj, dims, preferred_element_type=F32)
-    a_acc[...] += jax.lax.dot_general(yi, xj, dims, preferred_element_type=F32)
+    g_acc[...] += jax.lax.dot_general(xi, xj, dims, precision=_HIGHEST,
+                                      preferred_element_type=F32)
+    a_acc[...] += jax.lax.dot_general(yi, xj, dims, precision=_HIGHEST,
+                                      preferred_element_type=F32)
 
     @pl.when(ni == n_n - 1)
     def _finish():

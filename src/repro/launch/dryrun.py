@@ -101,12 +101,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     stem = f"{arch}__{shape_name}__{mesh_name}" + (f"__{tag}" if tag else "")
     (out_dir / f"{stem}.json").write_text(json.dumps(result, indent=1))
     if save_hlo:
-        try:
-            import zstandard
-            (out_dir / f"{stem}.hlo.zst").write_bytes(
-                zstandard.ZstdCompressor(level=3).compress(text.encode()))
-        except Exception:
-            pass
+        import zstandard
+        (out_dir / f"{stem}.hlo.zst").write_bytes(
+            zstandard.ZstdCompressor(level=3).compress(text.encode()))
     return result
 
 

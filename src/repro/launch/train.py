@@ -122,16 +122,21 @@ def main(argv=None):
 
     if session is not None:
         stats = session.close()      # broker drain -> engine drain, in order
-        panel = {}
-        for r in session.results():
-            if not isinstance(r.value, Exception):
-                panel[r.stream_key] = r.value
+        results = session.results()
+        failed = [r for r in results if isinstance(r.value, Exception)]
+        panel = {r.stream_key: r.value for r in results
+                 if not isinstance(r.value, Exception)}
         print("[analysis] per-region DMD stability "
               "(closer to 0 = more stable dynamics):")
         for k in sorted(panel):
             print(f"  {k:32s} {panel[k]:.5f}")
         print(f"[analysis] stream latency: {session.latency_stats()}")
+        print(f"[analysis] failed analyses: {len(failed)}")
+        for r in failed[:3]:
+            print(f"  {r.stream_key}: {r.value!r}")
         print(f"[broker] {stats}")
+        if failed:
+            raise SystemExit(f"[analysis] {len(failed)} analyses failed")
     return float(metrics["loss"])
 
 

@@ -1,14 +1,11 @@
-"""Property tests (hypothesis; skipped where absent — CI installs the
-``[test]`` extra): VirtualClock scheduling invariants under randomized
-sleep plans, and an ``encode_batch``/``decode_batch`` round-trip property
-across codec × delta × dtype.  Deterministic spot-check versions of the
-clock invariants live in ``tests/test_clock.py`` and always run."""
+"""Property tests (hypothesis): VirtualClock scheduling invariants under
+randomized sleep plans, and an ``encode_batch``/``decode_batch`` round-trip
+property across codec × delta × dtype.  Deterministic spot-check versions
+of the clock invariants live in ``tests/test_clock.py``."""
 import threading
 
 import numpy as np
 import pytest
-
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core.records import StreamRecord, decode_batch, encode_batch
@@ -138,16 +135,21 @@ def record_batches(draw):
 def test_encode_decode_batch_roundtrip(recs, compress, delta):
     out = decode_batch(encode_batch(recs, compress=compress, delta=delta))
     assert len(out) == len(recs)
-    for a, b in zip(recs, out):
+    for i, (a, b) in enumerate(zip(recs, out)):
         assert (a.field_name, a.group_id, a.rank, a.step) == \
                (b.field_name, b.group_id, b.rank, b.step)
         assert b.payload.shape == np.asarray(a.payload).shape
         ref = np.asarray(a.payload, np.float32)   # wire format is f32
         if compress.startswith("int8"):
-            # closed-loop per-stream quantization: error bounded by each
-            # record's own quant step (ptp/254), never by chain position
-            ptp = float(ref.max() - ref.min()) if ref.size else 0.0
-            atol = max(ptp / 254.0 * 1.5, 1e-6)
+            # closed-loop per-stream quantization: error bounded by the
+            # quant step of what was quantized (max|src|/254), never by
+            # chain position.  A chained record quantizes its delta against
+            # the previous record's reconstruction, which decode returns.
+            chained = (delta and i > 0 and recs[i - 1].key() == a.key()
+                       and np.shape(recs[i - 1].payload) == ref.shape)
+            src = ref - out[i - 1].payload if chained else ref
+            amax = float(np.abs(src).max()) if src.size else 0.0
+            atol = max(amax / 254.0 * 1.5, 1e-6)
             np.testing.assert_allclose(ref, b.payload, atol=atol)
         elif delta:
             # float delta chains reconstruct to roundoff, not bitwise

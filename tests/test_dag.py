@@ -36,7 +36,9 @@ def test_dag_in_engine_with_alerting():
     alerts = []
 
     def alert_stage(key, score):
-        if score > 0.5:               # decaying stream => far from unit circle
+        # decaying stream (|lambda| = 0.55, score 0.2025) => far from the
+        # unit circle; the neutral rotation scores ~0
+        if score > 0.1:
             return ("UNSTABLE", key, score)
         return None                   # filtered: no sink entry, no fan-out
 

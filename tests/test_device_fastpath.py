@@ -59,7 +59,7 @@ def test_masked_solve_matches_svd_oracle(rng):
         for j in range(n_valid):
             snaps[:, j] = x
             x = A @ x
-        got = np.asarray(dmd._window_solve(snaps, n_valid, rank=4))
+        got = dmd._small_eigs(*dmd._window_operator(snaps, n_valid, rank=4))
         want = np.asarray(ref.window_eigs_ref(snaps, n_valid, 4))
         k = int(np.isfinite(got).sum())
         assert k >= 3
@@ -92,14 +92,14 @@ def test_batched_window_dmd_empty_and_short_panes(rng):
 def test_window_solve_jit_cache_is_bucketed(rng):
     """Pane (d, m) shapes pad to power-of-two buckets, so streaming ragged
     panes compiles O(log) solver variants, not one per shape."""
-    before = dmd._window_solve._cache_size()
+    before = dmd._window_operator._cache_size()
     for m in range(3, 18):
         pane = [rng.randn(20).astype(np.float32) for _ in range(m)]
         window_dmd(pane, rank=4, n_features=20)
     # d=20 pads to one row bucket (32); m in 3..17 pads to cols {4,8,16,32}
-    assert dmd._window_solve._cache_size() - before <= 4
+    assert dmd._window_operator._cache_size() - before <= 4
 
-    solver = dmd._batched_solver(4)
+    solver = dmd._batched_operator(4)
     before_b = solver._cache_size()
     for k in (1, 2, 3, 5, 7, 9):
         panes = _linear_panes(rng, 20, [6] * k)

@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 from repro.analysis.dmd import StreamingDMD, gram_pair_update
-from repro.core import records as rec_mod
 from repro.core.broker import Broker, BrokerConfig, _GroupSender
 from repro.core.grouping import GroupPlan
 from repro.core.records import (StreamRecord, decode_any, decode_batch,
@@ -183,13 +182,14 @@ def test_batch_codec_mixed_streams_and_shapes(rng):
 
 
 @pytest.mark.parametrize("compress", ["none", "zstd", "int8", "int8+zstd"])
-def test_batch_codec_roundtrip_without_zstd(rng, monkeypatch, compress):
-    """zstandard absent: *zstd modes must fall back to plain framing."""
-    monkeypatch.setattr(rec_mod, "zstd", None)
+def test_batch_codec_frame_tags(rng, compress):
+    """*zstd modes always ship the compressed batch tag, the others plain
+    framing — zstandard is a hard dependency, so there is no silent
+    uncompressed fallback."""
     recs = [StreamRecord("f", 0, 0, s, rng.randn(16).astype(np.float32))
             for s in range(4)]
     blob = encode_batch(recs, compress=compress)
-    assert blob[:1] == b"B"                  # never the compressed tag
+    assert blob[:1] == (b"C" if compress.endswith("zstd") else b"B")
     out = decode_batch(blob)
     tol = 0.05 if compress.startswith("int8") else 0
     for a, b in zip(recs, out):
@@ -311,3 +311,30 @@ def test_engine_drain_flushes_held_records():
     broker.finalize()
     eng.drain_and_stop(timeout=10)              # force-flushes the hold
     assert sum(r.n_records for r in eng.collect()) == 1
+
+
+def test_zstd_frames_are_thread_safe(rng):
+    """Broker senders and endpoints each run on their own thread; frames
+    encoded and decoded concurrently must all round-trip (a zstd context
+    shared across threads corrupts frames)."""
+    import threading
+    recs = [StreamRecord("v", 0, r, 0, rng.randn(2304).astype(np.float32))
+            for r in range(32)]
+    want = decode_batch(encode_batch(recs, compress="zstd"))
+    errors = []
+
+    def work():
+        try:
+            for _ in range(10):
+                out = decode_batch(encode_batch(recs, compress="zstd"))
+                assert all(np.array_equal(a.payload, b.payload)
+                           for a, b in zip(out, want))
+        except Exception as e:            # collected for the main thread
+            errors.append(e)
+
+    threads = [threading.Thread(target=work) for _ in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errors, errors[:2]

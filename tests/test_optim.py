@@ -3,9 +3,6 @@ schedule shape."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.optim import adamw

@@ -1,10 +1,6 @@
 """Logical-axis rule resolution: divisibility fallback, no mesh-axis reuse."""
 import numpy as np
-import pytest
-
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
-
 from jax.sharding import PartitionSpec as P
 
 

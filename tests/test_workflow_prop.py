@@ -1,8 +1,5 @@
-"""Property tests for WorkflowConfig (hypothesis; skipped where absent —
-tests/test_workflow.py carries a deterministic grid version)."""
-import pytest
-
-pytest.importorskip("hypothesis")
+"""Property tests for WorkflowConfig (hypothesis; tests/test_workflow.py
+carries a deterministic grid version)."""
 from hypothesis import given, settings, strategies as st
 
 from repro.workflow import WorkflowConfig
