@@ -53,6 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime.telemetry import span
+
 F32 = jnp.float32
 _PRECISION = "highest"       # full-f32 device matmuls (see module docstring)
 # Every route keeps a direction only if its squared singular value s² (a
@@ -298,44 +300,61 @@ def batched_window_dmd(panes, rank: int = 8,
     k ragged panes cost O(distinct m-buckets) dispatches instead of k.
     Returns one eigenvalue array per pane, in input order; panes shorter
     than 3 snapshots get the zero sentinel, padding slots inside a bucket
-    are solved as empty panes and discarded."""
-    pane_rows = [_pane_rows(p) for p in panes]
-    out: list[np.ndarray | None] = [None] * len(pane_rows)
-    if n_features is None:
-        sizes = [r.size for rows in pane_rows for r in rows]
-        d = max(sizes) if sizes else 1
-    else:
-        d = int(n_features)
-    buckets: dict[int, list[int]] = {}
-    for i, rows in enumerate(pane_rows):
-        if len(rows) < 3:
-            out[i] = np.zeros(1, np.complex64)
+    are solved as empty panes and discarded.
+
+    Spans (``repro.runtime.telemetry.span``): ``analysis.solve`` around the
+    call; inside it ``analysis.fill`` (the panes' rows, then each bucket's
+    slab), ``analysis.transfer`` (each slab's host→device copy and the
+    solve's dispatch) and ``analysis.eig`` (each bucket's wait for the
+    device, copy back and host eigensolve)."""
+    with span("analysis.solve", panes=len(panes)) as solve_span:
+        with span("analysis.fill"):
+            pane_rows = [_pane_rows(p) for p in panes]
+        out: list[np.ndarray | None] = [None] * len(pane_rows)
+        if n_features is None:
+            sizes = [r.size for rows in pane_rows for r in rows]
+            d = max(sizes) if sizes else 1
         else:
-            buckets.setdefault(_pad_cols(len(rows)), []).append(i)
-    # fold buckets one power-of-two level apart into the wider one: the
-    # masked solve makes extra column padding exactly invariant, and one
-    # slightly wider slab beats a whole extra dispatch for the narrow panes
-    grouped: list[tuple[int, list[int]]] = []
-    for mp in sorted(buckets, reverse=True):
-        if grouped and mp * 2 >= grouped[-1][0]:
-            grouped[-1][1].extend(buckets[mp])
-        else:
-            grouped.append((mp, list(buckets[mp])))
-    dp = _pad_rows(max(d, 1))
-    solver = _batched_operator(rank)
-    pending = []                          # dispatch all, then sync once
-    for mp, idxs in grouped:
-        kp = _pad_rows(len(idxs))
-        slab = np.zeros((kp, dp, mp), np.float32)
-        nv = np.zeros(kp, np.int32)       # padding panes solve as empty
-        for slot, i in enumerate(idxs):
-            _fill_pane(slab[slot], pane_rows[i], d)
-            nv[slot] = len(pane_rows[i])
-        pending.append((idxs, solver(jnp.asarray(slab), jnp.asarray(nv))))
-    for idxs, (M, n_good) in pending:
-        eigs = _small_eigs(M, n_good)
-        for slot, i in enumerate(idxs):
-            out[i] = eigs[slot]
+            d = int(n_features)
+        buckets: dict[int, list[int]] = {}
+        for i, rows in enumerate(pane_rows):
+            if len(rows) < 3:
+                out[i] = np.zeros(1, np.complex64)
+            else:
+                buckets.setdefault(_pad_cols(len(rows)), []).append(i)
+        solve_span.set_metadata(snapshots=sum(
+            len(pane_rows[i]) for idxs in buckets.values() for i in idxs))
+        # fold buckets one power-of-two level apart into the wider one: the
+        # masked solve makes extra column padding exactly invariant, and one
+        # slightly wider slab beats a whole extra dispatch for the narrow
+        # panes
+        grouped: list[tuple[int, list[int]]] = []
+        for mp in sorted(buckets, reverse=True):
+            if grouped and mp * 2 >= grouped[-1][0]:
+                grouped[-1][1].extend(buckets[mp])
+            else:
+                grouped.append((mp, list(buckets[mp])))
+        dp = _pad_rows(max(d, 1))
+        solver = _batched_operator(rank)
+        pending = []                          # dispatch all, then sync once
+        for mp, idxs in grouped:
+            kp = _pad_rows(len(idxs))
+            with span("analysis.fill") as sp:
+                slab = np.zeros((kp, dp, mp), np.float32)
+                nv = np.zeros(kp, np.int32)   # padding panes solve as empty
+                for slot, i in enumerate(idxs):
+                    _fill_pane(slab[slot], pane_rows[i], d)
+                    nv[slot] = len(pane_rows[i])
+                sp.set_metadata(slab_bytes=slab.nbytes,
+                                valid_bytes=4 * d * int(nv.sum()))
+            with span("analysis.transfer", bytes=slab.nbytes):
+                pending.append(
+                    (idxs, solver(jnp.asarray(slab), jnp.asarray(nv))))
+        for idxs, (M, n_good) in pending:
+            with span("analysis.eig", panes=len(idxs)):
+                eigs = _small_eigs(M, n_good)
+            for slot, i in enumerate(idxs):
+                out[i] = eigs[slot]
     return out   # type: ignore[return-value]
 
 
@@ -350,7 +369,9 @@ def make_dmd_aggregate(rank: int = 8, n_features: int | None = None,
     across keys coalesce into one vmapped device dispatch — wire it as
     ``BatchAggregate("dmd", make_dmd_aggregate(...))``."""
     def batch_fn(items):
-        panes = [prepare(v) if prepare is not None else v for _k, v in items]
+        with span("analysis.prepare", panes=len(items)):
+            panes = [prepare(v) if prepare is not None else v
+                     for _k, v in items]
         return batched_window_dmd(panes, rank=rank, n_features=n_features)
     return batch_fn
 

@@ -43,6 +43,7 @@ from repro.core.records import (FieldSchema, StreamRecord, decode, encode,
                                 encode_batch, wrap_seq)
 from repro.core.transport import Transport
 from repro.runtime.clock import Clock, ensure_clock
+from repro.runtime.telemetry import span
 from repro.runtime.wal import WalSegment, WalStore
 from repro.tenancy import TenantAdmission, TenantRegistry, merge_counts
 
@@ -105,7 +106,6 @@ class BrokerStats:
     # after a failover or restart (also counted in frames_sent/sent)
     frames_replayed: int = 0
     records_replayed: int = 0
-    queue_high_water: int = 0
     # Effective deployment shape: a connect-time plan that asks for more
     # groups than there are endpoints is silently shrunk; these two fields
     # make that visible (planned != effective ⇒ mis-sized deployment).
@@ -128,14 +128,12 @@ class _SenderStats:
 
     __slots__ = ("lock", "written", "sent", "frames_sent", "dropped",
                  "rerouted", "bytes_sent", "send_errors", "frames_abandoned",
-                 "frames_replayed", "records_replayed", "queue_high_water",
-                 "tenants")
+                 "frames_replayed", "records_replayed", "tenants")
 
     def __init__(self):
         self.lock = threading.Lock()
         for f in _COUNTER_FIELDS:
             setattr(self, f, 0)
-        self.queue_high_water = 0
         # tenant -> counter dict (repro.tenancy.ledger.TENANT_COUNTERS);
         # stays empty unless the QoS plane is active
         self.tenants: dict[str, dict[str, int]] = {}
@@ -151,16 +149,9 @@ class _SenderStats:
             for name, d in deltas.items():
                 c[name] = c.get(name, 0) + d
 
-    def observe_depth(self, depth: int) -> None:
-        with self.lock:
-            if depth > self.queue_high_water:
-                self.queue_high_water = depth
-
     def snapshot(self) -> dict:
         with self.lock:
-            out = {f: getattr(self, f) for f in _COUNTER_FIELDS}
-            out["queue_high_water"] = self.queue_high_water
-            return out
+            return {f: getattr(self, f) for f in _COUNTER_FIELDS}
 
     def tenant_snapshot(self) -> dict[str, dict[str, int]]:
         with self.lock:
@@ -443,7 +434,6 @@ class _GroupSender(threading.Thread):
                 if self.wal.try_append(blob, rec) is not None:
                     break
                 self.clock.sleep(0.005)       # WAL full: bounded backpressure
-            self.stats.observe_depth(self.wal.unshipped_count())
             if self.tenants is not None:
                 # counted at append: try_append is atomic, so the per-tenant
                 # admitted count is exact across broker incarnations
@@ -455,11 +445,11 @@ class _GroupSender(threading.Thread):
         if self._exactly_once:
             return self._submit_eo([rec]) == 1
         self.stats.add(written=1)
-        self.stats.observe_depth(self.backlog())
         if self.tenants is not None:
             return self._submit_qos(rec, 1, rec.tenant) == 1
         if self.cfg.backpressure == "block":
-            self.clock.queue_put(self.q, rec)
+            with span("broker.enqueue", records=1):
+                self.clock.queue_put(self.q, rec)
             self._q_add(1)
             return True
         try:
@@ -498,8 +488,12 @@ class _GroupSender(threading.Thread):
         if self._exactly_once:
             return self._submit_eo(list(recs))
         self.stats.add(written=len(recs))
-        self.stats.observe_depth(self.backlog())
-        item = list(recs)
+        with span("broker.enqueue", records=len(recs)):
+            return self._admit_batch(list(recs))
+
+    def _admit_batch(self, item: list[StreamRecord]) -> int:
+        """Queue one batch under the backpressure policy (or the QoS
+        plane's admission); returns #records accepted."""
         if self.tenants is not None:
             # queue items must be single-tenant for priority eviction and
             # park accounting; mixed batches split (rare — FieldHandle and
@@ -583,11 +577,13 @@ class _GroupSender(threading.Thread):
                 recs.extend(nxt if isinstance(nxt, list) else [nxt])
             for i in range(0, len(recs), cap):
                 chunk = recs[i:i + cap]
-                if len(chunk) == 1:
-                    blob = encode(chunk[0], compress=self.cfg.compress)
-                else:
-                    blob = encode_batch(chunk, compress=self.cfg.compress,
-                                        delta=self.cfg.delta_encode)
+                with span("broker.encode", records=len(chunk)) as sp:
+                    if len(chunk) == 1:
+                        blob = encode(chunk[0], compress=self.cfg.compress)
+                    else:
+                        blob = encode_batch(chunk, compress=self.cfg.compress,
+                                            delta=self.cfg.delta_encode)
+                    sp.set_metadata(bytes=len(blob))
                 sent = self._send(blob)
                 # decremented only now: records stay on the backlog while
                 # the sender paces the frame out through the endpoint's
@@ -631,8 +627,10 @@ class _GroupSender(threading.Thread):
             else:
                 recs = [e.rec if e.rec is not None else decode(e.blob)
                         for e in entries]
-                blob = encode_batch(recs, compress=self.cfg.compress,
-                                    delta=self.cfg.delta_encode)
+                with span("broker.encode", records=len(recs)) as sp:
+                    blob = encode_batch(recs, compress=self.cfg.compress,
+                                        delta=self.cfg.delta_encode)
+                    sp.set_metadata(bytes=len(blob))
                 recs_n = len(recs)
             wire = wrap_seq(entries[0].seq, recs_n, blob)
             if not self._ship(wire, entries):
@@ -683,19 +681,20 @@ class _GroupSender(threading.Thread):
         """Send to primary; on failure re-route to the next healthy endpoint
         (pure remapping — the paper's grouping makes failover trivial)."""
         n = len(self.endpoints)
-        for attempt in range(self.cfg.retry_limit):
-            ep = self.endpoints[(self.primary + attempt) % n]
-            try:
-                if ep.healthy():
-                    ep.push(self.group_id, blob)
-                    if attempt > 0:
-                        self.stats.add(rerouted=1)
-                        self.primary = (self.primary + attempt) % n
-                    return True
-            except Exception:
-                pass
-            self.stats.add(send_errors=1)
-        return False
+        with span("broker.send", bytes=len(blob)):
+            for attempt in range(self.cfg.retry_limit):
+                ep = self.endpoints[(self.primary + attempt) % n]
+                try:
+                    if ep.healthy():
+                        ep.push(self.group_id, blob)
+                        if attempt > 0:
+                            self.stats.add(rerouted=1)
+                            self.primary = (self.primary + attempt) % n
+                        return True
+                except Exception:
+                    pass
+                self.stats.add(send_errors=1)
+            return False
 
     @staticmethod
     def _endpoint_load(ep) -> float | None:
@@ -948,8 +947,6 @@ class Broker:
             snap = s.stats_snapshot()
             for f in _COUNTER_FIELDS:
                 setattr(out, f, getattr(out, f) + snap[f])
-            out.queue_high_water = max(out.queue_high_water,
-                                       snap["queue_high_water"])
             if self.tenants is not None:
                 merge_counts(out.tenants, s.stats.tenant_snapshot())
         if self.tenants is not None:

@@ -18,6 +18,12 @@ history so policies can reason about trends, not just instants.  The bus
 holds weak expectations of its sources — anything exposing
 ``group_telemetry()`` / ``telemetry()`` / ``metrics()`` works — so it stays
 import-free of broker/engine internals.
+
+Where the counters say how much work a layer did, :func:`span` says when:
+each layer marks its units of work (a frame, a micro-batch, a window
+solve; never a record) as ``repro.<layer>.<what>`` host spans of the JAX
+profiler, with the unit's counts as the span's arguments, on the same clock
+as the device's operations in a profiler trace.
 """
 from __future__ import annotations
 
@@ -27,6 +33,20 @@ from collections import deque
 from dataclasses import dataclass
 
 from repro.runtime.clock import Clock, ensure_clock
+
+
+def span(name: str, **args):
+    """A ``repro.<name>`` host span around one unit of work, carrying
+    ``args`` (counts, identifiers) as its arguments; a context manager
+    whose ``set_metadata(**args)`` adds arguments known only at its end.
+
+    It records only while a ``jax.profiler`` trace runs, and costs about a
+    microsecond otherwise.  It never waits for the device: where device
+    work ends shows in the same trace."""
+    # imported here so that the broker, endpoint and engine modules stay
+    # importable without loading JAX
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(f"repro.{name}", **args)
 
 
 @dataclass(frozen=True)

@@ -13,6 +13,7 @@ from collections import defaultdict, deque
 
 from repro.core.records import StreamRecord, decode_any, unwrap_seq
 from repro.runtime.clock import Clock, ensure_clock
+from repro.runtime.telemetry import span
 from repro.runtime.wal import SeqLedger
 
 
@@ -107,8 +108,10 @@ class Endpoint:
             lag = self._bw_debt / self.inbound_bw
             if lag > 1e-4:
                 self.clock.sleep(min(lag, 0.05))
-        base, count, payload = unwrap_seq(blob)   # exactly-once seq header
-        recs = decode_any(payload)    # single-record or aggregated frame
+        with span("endpoint.decode") as sp:
+            base, count, payload = unwrap_seq(blob)   # exactly-once seq header
+            recs = decode_any(payload)    # single-record or aggregated frame
+            sp.set_metadata(records=len(recs))
         with self._lock:
             if self._drop_frames > 0:
                 self._drop_frames -= 1

@@ -41,6 +41,7 @@ from typing import Any, Callable
 
 from repro.core.records import StreamRecord
 from repro.runtime.clock import Clock, ensure_clock
+from repro.runtime.telemetry import span
 
 # A waiting executor proceeds out-of-order after this long rather than stall
 # the pipeline if its stream's ticket chain broke (a dropped partition with
@@ -127,31 +128,11 @@ class _Executor(threading.Thread):
             if mb is _POISON:
                 break
             self.current_key = mb.stream_key
-            plan = eng.plan
-            if plan is None:
-                self.waiting = True
-                eng._await_turn(mb)    # per-stream order even across steals
-                self.waiting = False
-                self.t_busy_since = clock.now()
-                if self.slowdown:
-                    clock.sleep(self.slowdown)
-                try:
-                    value = eng.analyze_fn(mb.stream_key, mb.records)
-                except Exception as e:  # analysis failure != engine failure
-                    value = e
-            else:
-                value = self._run_plan(plan, mb, clock)
-            tmin = min((r.t_generated for r in mb.records), default=mb.t_created)
-            by_tenant: dict[str, tuple[int, float]] = {}
-            for r in mb.records:
-                ent = by_tenant.get(r.tenant)
-                by_tenant[r.tenant] = (1, r.t_generated) if ent is None else \
-                    (ent[0] + 1, min(ent[1], r.t_generated))
-            eng._collect(Result(stream_key=mb.stream_key, value=value,
-                                n_records=len(mb.records),
-                                t_generated_min=tmin,
-                                t_analyzed=clock.now(), executor=self.idx,
-                                tenants=by_tenant))
+            # (stream, seq) ties this batch's wait to the spans inside it
+            with span("engine.run", stream=mb.stream_key, seq=mb.seq,
+                      records=len(mb.records),
+                      queued_us=int((clock.now() - mb.t_created) * 1e6)):
+                self._process(mb, clock)
             self.processed += 1
             self.current_key = None
             eng._release_turn(mb)
@@ -160,6 +141,35 @@ class _Executor(threading.Thread):
         # it was replaced and put the stolen run into its own dead queue)
         eng._reassign(self)
         clock.detach()     # exit the schedule without a watchdog stall
+
+    def _process(self, mb: MicroBatch, clock) -> None:
+        """Analyse one micro-batch and hand its Result to the engine."""
+        eng = self.engine
+        plan = eng.plan
+        if plan is None:
+            self.waiting = True
+            eng._await_turn(mb)    # per-stream order even across steals
+            self.waiting = False
+            self.t_busy_since = clock.now()
+            if self.slowdown:
+                clock.sleep(self.slowdown)
+            try:
+                value = eng.analyze_fn(mb.stream_key, mb.records)
+            except Exception as e:  # analysis failure != engine failure
+                value = e
+        else:
+            value = self._run_plan(plan, mb, clock)
+        tmin = min((r.t_generated for r in mb.records), default=mb.t_created)
+        by_tenant: dict[str, tuple[int, float]] = {}
+        for r in mb.records:
+            ent = by_tenant.get(r.tenant)
+            by_tenant[r.tenant] = (1, r.t_generated) if ent is None else \
+                (ent[0] + 1, min(ent[1], r.t_generated))
+        eng._collect(Result(stream_key=mb.stream_key, value=value,
+                            n_records=len(mb.records),
+                            t_generated_min=tmin,
+                            t_analyzed=clock.now(), executor=self.idx,
+                            tenants=by_tenant))
 
     def _run_plan(self, plan, mb: MicroBatch, clock) -> Any:
         """Plan-aware execution: the order-insensitive prefix runs BEFORE
@@ -600,7 +610,7 @@ class StreamEngine:
         now = self.clock.now()
         plan = self.plan
         shuffled = plan is not None and getattr(plan, "shuffled", False)
-        with self._tlock:
+        with span("engine.trigger") as sp, self._tlock:
             for ep in self.endpoints:
                 for key in ep.stream_keys():
                     recs = ep.drain(key)
@@ -632,6 +642,7 @@ class StreamEngine:
                                     t_created=now))
                 del self._hold[key], self._hold_t[key]
                 n += 1
+            sp.set_metadata(dispatched=n, held=len(self._hold))
         return n
 
     def held(self) -> int:

@@ -49,6 +49,7 @@ from typing import Any, Callable
 
 from repro.core.grouping import partition_of
 from repro.runtime.clock import Clock, ensure_clock
+from repro.runtime.telemetry import span
 
 ORDERED = "ordered"
 UNORDERED = "unordered"
@@ -228,7 +229,8 @@ class BatchAggregate(Operator):
             v = e.value
             values = list(v.values) if isinstance(v, WindowPane) else list(v)
             items.append((e.key, values))
-        outs = self.fn(items)
+        with span("operators.batch_aggregate", items=len(items)):
+            outs = self.fn(items)
         if len(outs) != len(elems):
             raise ValueError(
                 f"BatchAggregate {self.name!r}: fn returned {len(outs)} "
@@ -382,6 +384,7 @@ class _Window(Operator):
             if not live:
                 ctr["late_dropped"] += 1
                 if self._plan is not None:
+                    self._plan._count_late()
                     self._plan.emit_event("late_drop", op=self.name,
                                           key=elem.key, t_event=elem.t_event)
                 return
@@ -627,6 +630,8 @@ class ExecutionPlan:
         # active, micro-batches are key partitions, not producer streams,
         # and source elements carry each record's own stream key
         self._shuffle_n: int | None = None
+        # late drops of the batch this thread inserts (run_pre's span)
+        self._batch = threading.local()
         for op in self.ops.values():
             op.open(self)
 
@@ -741,6 +746,9 @@ class ExecutionPlan:
         a rebind covers every sink/window timestamp)."""
         self.clock = ensure_clock(clock)
 
+    def _count_late(self) -> None:
+        self._batch.late = getattr(self._batch, "late", 0) + 1
+
     def emit_event(self, kind: str, **detail) -> None:
         cb = self.on_event
         if cb is not None:
@@ -841,23 +849,31 @@ class ExecutionPlan:
         allowed = set(self._pre)
         elems = self._source_elements(key, records)
         primary = self._primary(key, records)
-        try:
-            for elem in elems:
-                if self.granularity == "batch" and self.source in allowed:
-                    primary = self._run_batch_source(
-                        elem, allowed, boundary, defer_fire=True)
-                else:
-                    self._feed(self.source, elem, allowed, boundary,
-                               defer_fire=True)
-        finally:
-            w = self._commit(
-                key, seq,
-                max((e.t_event for e in elems), default=float("-inf")))
-        for name in self._pre:
-            op = self.ops[name]
-            if isinstance(op, _Window):
-                self._fan_out(name, op.advance_watermark(w), allowed,
-                              boundary, defer_fire=True)
+        with span("operators.insert", records=len(records)) as sp:
+            self._batch.late = 0
+            try:
+                for elem in elems:
+                    if self.granularity == "batch" and self.source in allowed:
+                        primary = self._run_batch_source(
+                            elem, allowed, boundary, defer_fire=True)
+                    else:
+                        self._feed(self.source, elem, allowed, boundary,
+                                   defer_fire=True)
+            finally:
+                w = self._commit(
+                    key, seq,
+                    max((e.t_event for e in elems), default=float("-inf")))
+            sp.set_metadata(late=self._batch.late)
+        with span("operators.fire") as sp:
+            panes = 0
+            for name in self._pre:
+                op = self.ops[name]
+                if isinstance(op, _Window):
+                    fired = op.advance_watermark(w)
+                    panes += len(fired)
+                    self._fan_out(name, fired, allowed, boundary,
+                                  defer_fire=True)
+            sp.set_metadata(panes=panes)
         return _PreOut(boundary, primary)
 
     def run_post(self, key: str, pre_out: _PreOut | None, records: list):
