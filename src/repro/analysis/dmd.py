@@ -8,12 +8,12 @@ Three entry points, one eigensolve:
   points.  Both route through the *method-of-snapshots* reduction
   ``_masked_window_operator``: the reduced operator comes from the (m, m)
   snapshot Gram matrix instead of the (d, m) SVD, so a window of d=512
-  features costs one ``(d, m)·(d, m)`` einsum plus a small ``eigh``.
+  features costs one ``(m, d)·(m, d)`` einsum plus a small ``eigh``.
   Because validity is a mask rather than a shape, panes are zero-padded to
-  power-of-two buckets (features, snapshots, and — for the batched entry —
-  pane count), the jit cache stays O(log) across ragged windows, and
-  ``batched_window_dmd`` vmaps the whole reduction across co-fired panes in
-  a single device dispatch.
+  power-of-two buckets (snapshots, features where the caller does not fix
+  them, and — for the batched entry — pane count), the jit cache stays
+  O(log) across ragged windows, and ``batched_window_dmd`` vmaps the whole
+  reduction across co-fired panes in a single device dispatch.
 * ``StreamingDMD`` — online DMD over unbounded streams: Gram updates
   G += XᵀX, A += YᵀX over snapshot-pair blocks, eigenvalues from the
   Gram-space operator (``gram_eigs``).  This is what each stream's executor
@@ -192,22 +192,23 @@ def _pad_cols(n: int, minimum: int = 4) -> int:
 
 def _masked_window_operator(snaps: jax.Array, n_valid: jax.Array,
                             rank: int, rel_tol: float = _REL_TOL):
-    """Windowed DMD reduction on a zero-padded (d, m) pane, method of
-    snapshots.  Returns (M (r, r), #good directions) for ``_small_eigs``.
+    """Windowed DMD reduction on a zero-padded (m, d) pane of snapshot
+    rows, method of snapshots.  Returns (M (r, r), #good directions) for
+    ``_small_eigs``.
 
-    ``snaps`` holds ``n_valid`` real snapshot columns followed by zero
+    ``snaps`` holds ``n_valid`` real snapshot rows followed by zero
     padding; ``rank``/shapes are static, ``n_valid`` is data, so one
-    compiled variant serves every pane in the same (d, m) bucket and the
+    compiled variant serves every pane in the same (m, d) bucket and the
     whole thing vmaps across panes.
 
     ``rel_tol`` applies to s² (the Gram eigenvalues); see ``_REL_TOL``.
 
-    Exactness: with X = snaps[:, :n-1], Y = snaps[:, 1:n], exact DMD's
+    Exactness: with X = snaps[:n-1].T, Y = snaps[1:n].T, exact DMD's
     reduced operator is A~ = Uᵀ Y V S⁻¹ with X = U S Vᵀ.  Substituting
     Uᵀ = S⁻¹ Vᵀ Xᵀ gives A~' = S⁻¹ Vᵀ (XᵀY) V S⁻¹ — similar to A~ (same
     eigenvalues), and V/S² are the eigenvectors/eigenvalues of the small
-    (m-1)² Gram XᵀX.  Zero feature rows change neither Gram; zero snapshot
-    columns are removed by masking column ``n_valid - 1`` of X (the one
+    (m-1)² Gram XᵀX.  Zero feature columns change neither Gram; zero
+    snapshot rows are removed by masking row ``n_valid - 1`` of X (the one
     padded position that holds real data) out of both Grams.  Spurious
     directions (beyond the pane's true pair count or below ``rel_tol``)
     are zeroed out of the operator — block-triangular, so they contribute
@@ -215,8 +216,8 @@ def _masked_window_operator(snaps: jax.Array, n_valid: jax.Array,
     NaN, which consumers already filter.
     """
     with jax.default_matmul_precision(_PRECISION):
-        m = snaps.shape[1]
-        P = snaps.T @ snaps                       # (m, m) snapshot Gram
+        m = snaps.shape[0]
+        P = snaps @ snaps.T                       # (m, m) snapshot Gram
         lane = jnp.arange(m - 1)
         colmask = (lane < n_valid - 1).astype(F32)   # valid X columns
         mm = colmask[:, None] * colmask[None, :]
@@ -239,7 +240,8 @@ _window_operator = jax.jit(_masked_window_operator, static_argnames=("rank",))
 
 # one vmapped+jitted reduction per rank (rank is a config constant in
 # practice, so this dict stays O(1); the jit cache under each entry stays
-# O(log) thanks to power-of-two (k, d, m) bucketing by the callers)
+# O(log) thanks to power-of-two (k, m) bucketing by the callers, and d is
+# fixed by the configuration or bucketed too)
 _BATCH_OPERATORS: dict[int, object] = {}
 
 
@@ -255,15 +257,44 @@ def _pane_rows(snapshots) -> list[np.ndarray]:
     return [np.asarray(s, np.float32).reshape(-1) for s in snapshots]
 
 
-def _fill_pane(out: np.ndarray, rows: list[np.ndarray], d: int) -> None:
-    """Write a pane's snapshots into the (d_pad, m_pad) zero slab ``out``."""
-    if rows and all(r.size == rows[0].size for r in rows):
-        w = min(rows[0].size, d)        # uniform width: one C-level copy
-        out[:w, : len(rows)] = np.stack(rows, axis=1)[:w]
-        return
-    for j, r in enumerate(rows):
-        r = r[:d]
-        out[: r.size, j] = r
+def _feature_width(pane_rows, n_features: int | None) -> int:
+    """The slab's d: ``n_features`` as given (the configuration fixes it),
+    else the longest row rounded up to a power of two, so that inputs of
+    varying width reuse O(log) compiled variants."""
+    if n_features is not None:
+        return int(n_features)
+    return _pad_rows(max((r.size for rows in pane_rows for r in rows),
+                         default=1))
+
+
+def _slab(pane_rows, k_pad: int, m_pad: int,
+          d: int) -> tuple[np.ndarray, int]:
+    """The (k_pad, m_pad, d) float32 slab of the panes' snapshot rows
+    (``_pane_rows``), zero-padded, and the number of host copy calls that
+    built it.
+
+    Where every row is C-contiguous and exactly d floats long, one
+    ``bytes.join`` gathers them, a shared zero row filling the padding:
+    one copy call per slab, where a copy per row (or per pane) hands the
+    interpreter lock to any busy thread thousands of times a solve and
+    waits up to a switch interval to take it back each time.  Other rows
+    are trimmed or zero-padded to d one at a time into the same layout."""
+    rows = [r for p in pane_rows for r in p]
+    if all(r.size == d and r.flags.c_contiguous for r in rows):
+        zero = bytes(4 * d)
+        parts = []
+        for p in pane_rows:
+            parts.extend(p)
+            parts.extend([zero] * (m_pad - len(p)))
+        parts.extend([zero] * (m_pad * (k_pad - len(pane_rows))))
+        slab = np.frombuffer(b"".join(parts), np.float32)
+        return slab.reshape(k_pad, m_pad, d), 1
+    slab = np.zeros((k_pad, m_pad, d), np.float32)
+    for slot, p in enumerate(pane_rows):
+        for j, r in enumerate(p):
+            w = min(r.size, d)
+            slab[slot, j, :w] = r[:w]
+    return slab, len(rows)
 
 
 def window_dmd(snapshots, rank: int = 8,
@@ -272,21 +303,21 @@ def window_dmd(snapshots, rank: int = 8,
 
     ``snapshots``: iterable of 1-D arrays (a fired window's values, e.g.
     record payloads in step order).  Each is flattened and trimmed /
-    zero-padded to ``n_features`` (default: the longest snapshot).  The
-    pane is zero-padded to a power-of-two (d, m) bucket before the masked
-    solve, so sliding windows with ragged tails reuse O(log) compiled
-    variants instead of one per pane size.  Windows shorter than 3
-    snapshots can't form a snapshot pair worth solving — returns the same
-    zero sentinel ``StreamingDMD.eigenvalues`` uses.  Null/padded
-    directions come back NaN; consumers filter non-finite entries."""
+    zero-padded to ``n_features`` (default: the longest snapshot, rounded
+    up to a power of two).  The pane's rows are zero-padded to a
+    power-of-two count before the masked solve, so sliding windows with
+    ragged tails reuse O(log) compiled variants instead of one per pane
+    size.  Windows shorter than 3 snapshots can't form a snapshot pair
+    worth solving — returns the same zero sentinel
+    ``StreamingDMD.eigenvalues`` uses.  Null/padded directions come back
+    NaN; consumers filter non-finite entries."""
     rows = _pane_rows(snapshots)
     if len(rows) < 3:
         return np.zeros(1, np.complex64)
-    d = max(r.size for r in rows) if n_features is None else int(n_features)
     m = len(rows)
-    pane = np.zeros((_pad_rows(max(d, 1)), _pad_cols(m)), np.float32)
-    _fill_pane(pane, rows, d)
-    return _small_eigs(*_window_operator(jnp.asarray(pane), jnp.int32(m),
+    slab, _copies = _slab([rows], 1, _pad_cols(m),
+                          _feature_width([rows], n_features))
+    return _small_eigs(*_window_operator(jnp.asarray(slab[0]), jnp.int32(m),
                                          rank=rank))
 
 
@@ -295,27 +326,25 @@ def batched_window_dmd(panes, rank: int = 8,
     """Multi-key windowed DMD: solve many co-fired panes in one dispatch.
 
     ``panes``: sequence of snapshot iterables (one fired pane per key /
-    stream).  Panes are zero-padded into power-of-two (k, d, m) buckets and
-    each bucket goes through one vmapped ``_masked_window_operator`` call —
-    k ragged panes cost O(distinct m-buckets) dispatches instead of k.
-    Returns one eigenvalue array per pane, in input order; panes shorter
-    than 3 snapshots get the zero sentinel, padding slots inside a bucket
-    are solved as empty panes and discarded.
+    stream).  Panes are zero-padded into power-of-two (k, m) buckets of
+    (k, m, d) row slabs, d as ``window_dmd`` takes it, and each bucket goes
+    through one vmapped ``_masked_window_operator`` call — k ragged panes
+    cost O(distinct m-buckets) dispatches instead of k.  Returns one
+    eigenvalue array per pane, in input order; panes shorter than 3
+    snapshots get the zero sentinel, padding slots inside a bucket are
+    solved as empty panes and discarded.
 
     Spans (``repro.runtime.telemetry.span``): ``analysis.solve`` around the
     call; inside it ``analysis.fill`` (the panes' rows, then each bucket's
-    slab), ``analysis.transfer`` (each slab's host→device copy and the
+    slab: its bytes, the valid ones, and the host copy calls that built
+    it), ``analysis.transfer`` (each slab's host→device copy and the
     solve's dispatch) and ``analysis.eig`` (each bucket's wait for the
     device, copy back and host eigensolve)."""
     with span("analysis.solve", panes=len(panes)) as solve_span:
         with span("analysis.fill"):
             pane_rows = [_pane_rows(p) for p in panes]
         out: list[np.ndarray | None] = [None] * len(pane_rows)
-        if n_features is None:
-            sizes = [r.size for rows in pane_rows for r in rows]
-            d = max(sizes) if sizes else 1
-        else:
-            d = int(n_features)
+        d = _feature_width(pane_rows, n_features)
         buckets: dict[int, list[int]] = {}
         for i, rows in enumerate(pane_rows):
             if len(rows) < 3:
@@ -334,19 +363,17 @@ def batched_window_dmd(panes, rank: int = 8,
                 grouped[-1][1].extend(buckets[mp])
             else:
                 grouped.append((mp, list(buckets[mp])))
-        dp = _pad_rows(max(d, 1))
         solver = _batched_operator(rank)
         pending = []                          # dispatch all, then sync once
         for mp, idxs in grouped:
             kp = _pad_rows(len(idxs))
             with span("analysis.fill") as sp:
-                slab = np.zeros((kp, dp, mp), np.float32)
+                slab, copies = _slab([pane_rows[i] for i in idxs], kp, mp, d)
                 nv = np.zeros(kp, np.int32)   # padding panes solve as empty
-                for slot, i in enumerate(idxs):
-                    _fill_pane(slab[slot], pane_rows[i], d)
-                    nv[slot] = len(pane_rows[i])
+                nv[: len(idxs)] = [len(pane_rows[i]) for i in idxs]
                 sp.set_metadata(slab_bytes=slab.nbytes,
-                                valid_bytes=4 * d * int(nv.sum()))
+                                valid_bytes=4 * d * int(nv.sum()),
+                                copies=copies)
             with span("analysis.transfer", bytes=slab.nbytes):
                 pending.append(
                     (idxs, solver(jnp.asarray(slab), jnp.asarray(nv))))

@@ -73,11 +73,12 @@ def dequant_ref(data: jax.Array, scale: jax.Array) -> jax.Array:
 
 def window_eigs_ref(snaps: jax.Array, n_valid: int, rank: int) -> jax.Array:
     """Oracle for ``analysis.dmd._masked_window_operator`` + ``_small_eigs``:
-    SVD-route exact DMD on the *valid slice* of a zero-padded (d, m) pane,
-    eigenvalues sorted by descending magnitude.  Host-side only (``n_valid``
-    must be concrete; the masked solve exists precisely to avoid this
-    dynamic slice).  CPU-only oracle: ``jnp.linalg.eigvals`` has no TPU
-    lowering, which is why the solve under test takes its small
+    SVD-route exact DMD on the *valid slice* of a zero-padded (d, m) pane
+    of snapshot columns (the transpose of the (m, d) rows the operator
+    takes), eigenvalues sorted by descending magnitude.  Host-side only
+    (``n_valid`` must be concrete; the masked solve exists precisely to
+    avoid this dynamic slice).  CPU-only oracle: ``jnp.linalg.eigvals`` has
+    no TPU lowering, which is why the solve under test takes its small
     eigensolve on the host."""
     X = snaps[:, : n_valid - 1].astype(F32)
     Y = snaps[:, 1:n_valid].astype(F32)

@@ -742,10 +742,15 @@ class StreamEngine:
             for e in self.executors:
                 if not e.alive and e.q.qsize() and self._alive():
                     self._reassign(e)
-            pending = sum(ep.pending() for ep in self.endpoints)
-            queued = sum(e.q.qsize() for e in self._alive())
-            stranded = sum(e.q.qsize() for e in self.executors if not e.alive)
-            if pending == 0 and queued == 0 and self.held() == 0 \
+            # under the trigger lock: a trigger in flight holds records it
+            # took from the endpoints and has not queued yet
+            with self._tlock:
+                pending = sum(ep.pending() for ep in self.endpoints)
+                queued = sum(e.q.qsize() for e in self._alive())
+                stranded = sum(e.q.qsize() for e in self.executors
+                               if not e.alive)
+                held = self.held()
+            if pending == 0 and queued == 0 and held == 0 \
                     and (stranded == 0 or not self._alive()):
                 break
             self.trigger_once(force=True)

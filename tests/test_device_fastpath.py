@@ -59,7 +59,9 @@ def test_masked_solve_matches_svd_oracle(rng):
         for j in range(n_valid):
             snaps[:, j] = x
             x = A @ x
-        got = dmd._small_eigs(*dmd._window_operator(snaps, n_valid, rank=4))
+        # the operator takes the pane as (m, d) rows, the oracle as columns
+        got = dmd._small_eigs(*dmd._window_operator(snaps.T, n_valid,
+                                                    rank=4))
         want = np.asarray(ref.window_eigs_ref(snaps, n_valid, 4))
         k = int(np.isfinite(got).sum())
         assert k >= 3
@@ -90,13 +92,14 @@ def test_batched_window_dmd_empty_and_short_panes(rng):
 
 
 def test_window_solve_jit_cache_is_bucketed(rng):
-    """Pane (d, m) shapes pad to power-of-two buckets, so streaming ragged
-    panes compiles O(log) solver variants, not one per shape."""
+    """Pane (m, d) shapes pad to power-of-two buckets in m (d is fixed by
+    ``n_features``), so streaming ragged panes compiles O(log) solver
+    variants, not one per shape."""
     before = dmd._window_operator._cache_size()
     for m in range(3, 18):
         pane = [rng.randn(20).astype(np.float32) for _ in range(m)]
         window_dmd(pane, rank=4, n_features=20)
-    # d=20 pads to one row bucket (32); m in 3..17 pads to cols {4,8,16,32}
+    # d=20 stays 20; m in 3..17 pads to row buckets {4,8,16,32}
     assert dmd._window_operator._cache_size() - before <= 4
 
     solver = dmd._batched_operator(4)
@@ -115,6 +118,69 @@ def test_make_dmd_aggregate_prepares_and_scores(rng):
     assert len(outs) == 2
     for eigs in outs:
         assert np.isfinite(unit_circle_distance(eigs))
+
+
+# ------------------------------------------------------ slab of the batched solve
+def _per_row_slab(panes, k_pad, m_pad, d):
+    """Reference fill: each row trimmed or zero-padded to d, written one at
+    a time into a (k_pad, m_pad, d) zero slab."""
+    slab = np.zeros((k_pad, m_pad, d), np.float32)
+    for slot, rows in enumerate(panes):
+        for j, r in enumerate(rows):
+            r = np.asarray(r, np.float32).reshape(-1)[:d]
+            slab[slot, j, : r.size] = r
+    return slab
+
+
+@pytest.mark.parametrize("lengths, k_pad, m_pad", [
+    ([8, 8, 8], 4, 8),                 # uniform panes, one padding pane
+    ([3, 5, 8, 7, 4], 8, 8),           # ragged m: padding rows and panes
+])
+def test_joined_slab_matches_per_row_fill(rng, lengths, k_pad, m_pad):
+    d = 24
+    panes = [dmd._pane_rows(rng.randn(m, d).astype(np.float32))
+             for m in lengths]
+    slab, copies = dmd._slab(panes, k_pad, m_pad, d)
+    assert copies == 1
+    assert slab.shape == (k_pad, m_pad, d) and slab.dtype == np.float32
+    assert slab.tobytes() == _per_row_slab(panes, k_pad, m_pad, d).tobytes()
+
+
+@pytest.mark.parametrize("form", ["ragged_width", "strided"])
+def test_slab_fallback_matches_joined_gather(rng, form):
+    """Rows the join cannot take whole (another width than d, or not
+    contiguous) are copied one at a time into the same slab."""
+    d, lengths, k_pad, m_pad = 24, [3, 5, 8, 7], 4, 8
+    if form == "ragged_width":
+        widths = [d - 5, d, d + 6]
+        panes = [dmd._pane_rows(rng.randn(widths[j % 3]).astype(np.float32)
+                                for j in range(m)) for m in lengths]
+    else:                              # columns of (d, m) arrays
+        panes = [dmd._pane_rows(rng.randn(d, m).astype(np.float32).T)
+                 for m in lengths]
+    assert not all(r.size == d and r.flags.c_contiguous
+                   for p in panes for r in p)
+    slab, copies = dmd._slab(panes, k_pad, m_pad, d)
+    assert copies == sum(lengths)
+    want = _per_row_slab(panes, k_pad, m_pad, d)
+    assert slab.tobytes() == want.tobytes()
+    whole = [list(want[slot, :m]) for slot, m in enumerate(lengths)]
+    joined, one = dmd._slab(whole, k_pad, m_pad, d)
+    assert one == 1 and joined.tobytes() == slab.tobytes()
+
+
+def test_unpadded_width_matches_padded_bucket(rng):
+    """d = 2304 as the configuration gives it solves as the same panes
+    zero-padded to the power-of-two bucket 4096 did."""
+    d = 2304
+    panes = _linear_panes(rng, d, [32, 32, 20, 9])
+    got = batched_window_dmd(panes, rank=4, n_features=d)
+    want = batched_window_dmd(panes, rank=4, n_features=4096)
+    for g, w in zip(got, want):
+        finite = np.isfinite(w)
+        assert finite.sum() >= 3
+        assert np.array_equal(finite, np.isfinite(g))
+        assert np.allclose(g[finite], w[finite], atol=1e-5)
 
 
 # ------------------------------------------------ StreamingDMD: cache + donation

@@ -138,7 +138,7 @@ def test_span_counts_match_the_program_counters(traced):
     assert total("analysis.solve", "snapshots") == out["sent"]
     assert total("operators.insert", "late") == 0
     # one bucket per solve (every pane holds PANE snapshots): its slab is
-    # (k_pad, d_pad, m_pad) float32
+    # (k_pad, m_pad, d) float32, d as the configuration gives it
     solves = [sp for sp in spans if sp["name"] == "analysis.solve"]
     transfers = [sp for sp in spans if sp["name"] == "analysis.transfer"]
     assert len(transfers) == len(solves)
@@ -146,10 +146,23 @@ def test_span_counts_match_the_program_counters(traced):
         (t,) = [t for t in transfers if t["line"] == solve["line"]
                 and solve["start"] <= t["start"] <= t["end"] <= solve["end"]]
         assert t["args"]["bytes"] == (_pad_rows(solve["args"]["panes"])
-                                      * _pad_rows(D) * _pad_cols(PANE) * 4)
+                                      * _pad_cols(PANE) * D * 4)
     k_pad = sum(_pad_rows(sp["args"]["panes"]) for sp in solves)
     assert total("analysis.transfer", "bytes") == \
-        k_pad * _pad_rows(D) * _pad_cols(PANE) * 4
+        k_pad * _pad_cols(PANE) * D * 4
+
+
+def test_slab_fill_is_one_copy_per_bucket(traced):
+    """Every decoded payload is a contiguous row of D floats, so each
+    bucket's slab is gathered in one host copy call."""
+    _out, spans = traced
+    slabs = [sp for sp in spans
+             if sp["name"] == "analysis.fill" and "slab_bytes" in sp["args"]]
+    transfers = [sp for sp in spans if sp["name"] == "analysis.transfer"]
+    assert len(slabs) == len(transfers) > 0
+    for sp in slabs:
+        assert sp["args"]["copies"] == 1
+        assert sp["args"]["slab_bytes"] % (_pad_cols(PANE) * D * 4) == 0
 
 
 def test_engine_run_nests_the_insert_on_its_thread(traced):

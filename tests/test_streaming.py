@@ -71,6 +71,51 @@ def test_work_stealing_absorbs_straggler():
     assert sum(r.n_records for r in eng.collect()) == 24
 
 
+def test_drain_waits_out_a_trigger_in_flight():
+    """``drain_and_stop`` must not stop while a trigger holds records it
+    has taken from the endpoints but not yet queued: the executor, busy
+    meanwhile, would exit with them queued and no survivor to run them."""
+    import threading
+    busy = threading.Event()
+
+    def analyze(key, recs):
+        busy.wait(5.0)                     # the one executor stays busy
+        return len(recs)
+
+    broker, eps, eng = _mk_engine(n_exec=1, analyze=analyze, trigger=30,
+                                  n_ranks=1)
+    broker.write("f", 0, 0, np.zeros(8, np.float32))
+    broker.flush()
+    assert eng.trigger_once(force=True) == 1
+    broker.write("f", 0, 1, np.ones(8, np.float32))
+    broker.flush()
+    # a trigger in flight: it has drained the endpoint and not yet queued
+    picking, go = threading.Event(), threading.Event()
+    pick = eng._pick_executor
+
+    def slow_pick(*args, **kw):
+        picking.set()
+        go.wait(5.0)
+        return pick(*args, **kw)
+
+    eng._pick_executor = slow_pick
+    trigger = threading.Thread(target=eng.trigger_once,
+                               kwargs={"force": True})
+    trigger.start()
+    assert picking.wait(5.0)
+    del eng._pick_executor
+    drain = threading.Thread(target=eng.drain_and_stop)
+    drain.start()
+    time.sleep(0.2)                        # the drain looks meanwhile
+    go.set()
+    trigger.join(5.0)
+    time.sleep(0.2)                        # the drain decides meanwhile
+    busy.set()
+    drain.join(30.0)
+    assert not trigger.is_alive() and not drain.is_alive()
+    assert sum(r.n_records for r in eng.collect()) == 2
+
+
 def test_executor_failure_reassigns():
     broker, eps, eng = _mk_engine(n_exec=2, trigger=10)  # driver won't fire
     _push(broker, steps=4)

@@ -88,8 +88,8 @@ def test_dequantize_kernel_compiles_multi_step_grid(sds, rows):
 
 def test_batched_window_reduction_compiles(sds):
     """The device half of ``batched_window_dmd``: 16 co-fired panes of up
-    to 32 snapshots, d=2304 padded to its 4096 bucket."""
-    _compiled_text(dmd._batched_operator(8), sds((16, 4096, 32)),
+    to 32 snapshot rows of d=2304, unpadded."""
+    _compiled_text(dmd._batched_operator(8), sds((16, 32, D)),
                    sds((16,), jnp.int32))
 
 
