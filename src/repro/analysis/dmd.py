@@ -484,47 +484,60 @@ class StreamingDMD:
         """Batched update: ``snaps`` is an (n, d) array or list of snapshots
         (each trimmed/zero-padded to ``n_features``).  Forms the shifted
         X = chain[:-1], Y = chain[1:] pair — chaining through the previous
-        batch's last snapshot — and applies it in one device call."""
+        batch's last snapshot — and applies it in one device call.  Span
+        ``analysis.stream_update``: the snapshots given (``rows``), the pair
+        rows shipped after padding (``padded_rows``) and the bytes of X and
+        Y sent to the device (``bytes``)."""
         block = self._coerce_block(snaps)
         if block.shape[0] == 0:
             return
-        if self.last_snapshot is not None:
-            chain = np.concatenate([self.last_snapshot[None], block])
-        else:
-            chain = block
-        X, Y = chain[:-1], chain[1:]
-        n = X.shape[0]
-        if n:
-            m = _pad_rows(n)
-            if m != n:   # zero rows contribute nothing to XᵀX / YᵀX
-                pad = np.zeros((m - n, self.n_features), np.float32)
-                X = np.concatenate([X, pad])
-                Y = np.concatenate([Y, pad])
-            self._apply_pair_block(X, Y)
-        self.last_snapshot = np.ascontiguousarray(chain[-1])
-        self._buf.extend(block)
-        del self._buf[: max(0, len(self._buf) - self.window)]
-        self.n_seen += block.shape[0]
+        with span("analysis.stream_update", rows=block.shape[0]) as sp:
+            if self.last_snapshot is not None:
+                chain = np.concatenate([self.last_snapshot[None], block])
+            else:
+                chain = block
+            X, Y = chain[:-1], chain[1:]
+            n = X.shape[0]
+            m = _pad_rows(n) if n else 0
+            if n:
+                if m != n:   # zero rows contribute nothing to XᵀX / YᵀX
+                    pad = np.zeros((m - n, self.n_features), np.float32)
+                    X = np.concatenate([X, pad])
+                    Y = np.concatenate([Y, pad])
+                self._apply_pair_block(X, Y)
+            sp.set_metadata(padded_rows=m, bytes=2 * m * self.n_features * 4)
+            self.last_snapshot = np.ascontiguousarray(chain[-1])
+            self._buf.extend(block)
+            del self._buf[: max(0, len(self._buf) - self.window)]
+            self.n_seen += block.shape[0]
 
     def eigenvalues(self) -> np.ndarray:
         """Current DMD eigenvalues.  Cached: a second call with no update
         in between returns the previous solve without touching the device
         (telemetry re-reads stop re-running ``gram_eigs`` on unchanged
-        G/A — watch ``device_calls`` stand still)."""
-        if self._eigs_cache is not None and self._eigs_seen == self.n_seen:
-            return self._eigs_cache
-        if self.n_seen < 3:
-            eigs = np.zeros(1, np.complex64)
-        elif self.n_seen <= self.window:
-            snaps = jnp.asarray(np.stack(self._buf, axis=1))
-            self.h2d_transfers += 1
-            self.device_calls += 1
-            eigs, _ = exact_dmd(snaps, rank=self.rank)
-            self.d2h_transfers += 1
-        else:
-            self.device_calls += 1
-            eigs = gram_eigs(self._G, self._A, rank=self.rank)
-            self.d2h_transfers += 1
-        self._eigs_cache = eigs
-        self._eigs_seen = self.n_seen
-        return eigs
+        G/A — watch ``device_calls`` stand still).  Span
+        ``analysis.stream_eig``: the route taken (``cached``; ``exact`` while
+        the stream has seen at most ``window`` snapshots; ``gram`` past
+        that) and ``n_seen``."""
+        cached = (self._eigs_cache is not None
+                  and self._eigs_seen == self.n_seen)
+        route = ("cached" if cached
+                 else "exact" if self.n_seen <= self.window else "gram")
+        with span("analysis.stream_eig", route=route, n_seen=self.n_seen):
+            if cached:
+                return self._eigs_cache
+            if self.n_seen < 3:
+                eigs = np.zeros(1, np.complex64)
+            elif route == "exact":
+                snaps = jnp.asarray(np.stack(self._buf, axis=1))
+                self.h2d_transfers += 1
+                self.device_calls += 1
+                eigs, _ = exact_dmd(snaps, rank=self.rank)
+                self.d2h_transfers += 1
+            else:
+                self.device_calls += 1
+                eigs = gram_eigs(self._G, self._A, rank=self.rank)
+                self.d2h_transfers += 1
+            self._eigs_cache = eigs
+            self._eigs_seen = self.n_seen
+            return eigs

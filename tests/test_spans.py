@@ -5,14 +5,16 @@ Under a ``jax.profiler`` trace, a small windowed-DMD session (a few ranks,
 int8+zstd frames, a keyed tumbling window into
 ``BatchAggregate(make_dmd_aggregate(...))``) records every layer's span on
 the host plane, one line per thread, with the counts each span carries as
-its arguments."""
+its arguments.  Online DMD (``StreamingDMD``) records one span per update
+and one per eigensolve, with the route the solve took."""
 from pathlib import Path
 
 import jax
 import numpy as np
 import pytest
 
-from repro.analysis.dmd import _pad_cols, _pad_rows, make_dmd_aggregate
+from repro.analysis.dmd import (StreamingDMD, _pad_cols, _pad_rows,
+                                make_dmd_aggregate)
 from repro.runtime.telemetry import span
 from repro.workflow import OperatorPipeline, Session, WorkflowConfig
 
@@ -176,3 +178,44 @@ def test_engine_run_nests_the_insert_on_its_thread(traced):
         assert run["args"]["records"] == ins["args"]["records"]
         assert run["args"]["queued_us"] >= 0
         assert run["args"]["stream"].startswith("field/")
+
+
+STREAM_WINDOW = 4
+
+
+def _stream_updates() -> list[np.ndarray]:
+    """Feed one StreamingDMD blocks of 1, 2, 3 and 1 seeded snapshots,
+    asking for its eigenvalues twice after each; returns every answer."""
+    data = np.random.default_rng(7).standard_normal((7, D)).astype(np.float32)
+    sd = StreamingDMD(n_features=D, window=STREAM_WINDOW, rank=RANK)
+    out = []
+    for lo, hi in ((0, 1), (1, 3), (3, 6), (6, 7)):
+        sd.update_batch(data[lo:hi])
+        out += [sd.eigenvalues(), sd.eigenvalues()]
+    return out
+
+
+def test_stream_spans_carry_rows_padding_bytes_and_route(tmp_path):
+    _trace(tmp_path, _stream_updates)
+    spans = _read_spans(tmp_path)
+    updates = [sp["args"] for sp in spans
+               if sp["name"] == "analysis.stream_update"]
+    # the first block pairs rows - 1 snapshots, later ones chain through
+    # the previous block's last snapshot; X and Y ship padded, float32
+    assert [(a["rows"], a["padded_rows"]) for a in updates] == \
+        [(1, 0), (2, 2), (3, 4), (1, 1)]
+    for a in updates:
+        assert a["bytes"] == 2 * a["padded_rows"] * D * 4
+    eigs = [(sp["args"]["route"], sp["args"]["n_seen"]) for sp in spans
+            if sp["name"] == "analysis.stream_eig"]
+    assert eigs == [("exact", 1), ("cached", 1), ("exact", 3), ("cached", 3),
+                    ("gram", 6), ("cached", 6), ("gram", 7), ("cached", 7)]
+
+
+def test_untraced_stream_spans_record_nothing_and_change_no_result(tmp_path):
+    traced = _trace(tmp_path / "on", _stream_updates)
+    untraced = _stream_updates()
+    _trace(tmp_path / "after", lambda: None)
+    assert _read_spans(tmp_path / "after") == []
+    for a, b in zip(traced, untraced, strict=True):
+        np.testing.assert_array_equal(a, b)
