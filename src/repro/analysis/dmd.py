@@ -16,15 +16,20 @@ Three entry points, one eigensolve:
   reduction across co-fired panes in a single device dispatch.
 * ``StreamingDMD`` — online DMD over unbounded streams: Gram updates
   G += XᵀX, A += YᵀX over snapshot-pair blocks, eigenvalues from the
-  Gram-space operator (``gram_eigs``).  This is what each stream's executor
-  runs per micro-batch.
+  Gram-space operator (``_gram_operator``): the full ``eigh`` of G on a
+  stream's first Gram solve (``gram_eigs``), then block subspace iteration
+  warm-started from the previous solve's basis.  This is what each
+  stream's executor runs per micro-batch.
 
 Every path splits the same way.  The device does the heavy work — Gram
 products, the thin SVD or ``eigh``, and the projection down to a rank-r
 reduced operator (r <= ``rank``, a ``(k, r, r)`` stack for batched panes)
 — and ``_small_eigs`` takes the eigenvalues of those r×r operators on the
 host with ``np.linalg.eigvals``: a nonsymmetric eigensolve has no TPU
-lowering, and 64 floats per pane is all that crosses back.  The same path
+lowering, and 64 floats per pane is all that crosses back.  The Gram
+route stops one step earlier: the device projects G and A onto G's top-p
+Ritz basis, and the host finishes the p×p eigh and the projection to r×r
+in float64 (``_ritz_eigs``).  The same path
 runs on every backend, so CPU tests cover the code the chip runs.  The
 device matmuls run at full f32 precision (``_PRECISION``): TPU's default
 single bf16 pass would bury every direction below ~1e-2 of the leading
@@ -39,7 +44,9 @@ elsewhere — instead of one ``G += x xᵀ, A += y xᵀ`` dispatch (plus four
 host↔device transfers) per snapshot.  The update **donates** G/A into the
 jitted accumulator (``donate_argnums``) so XLA updates them in place
 instead of allocating a fresh (d, d) pair per micro-batch, and
-``eigenvalues()`` caches its last solve until the next update lands.
+``eigenvalues()`` caches its last solve until the next update lands.  The
+Gram solve's top-p Ritz basis stays on the device too, as the next solve's
+warm start.
 ``h2d_transfers`` / ``d2h_transfers`` / ``device_calls`` counters make the
 savings measurable (benchmarks/kernels_bench.py writes them to
 BENCH_hotpath.json / BENCH_multikey.json).
@@ -143,27 +150,119 @@ gram_pair_update = jax.jit(_gram_pair_raw)
 gram_pair_update_donated = jax.jit(_gram_pair_raw, donate_argnums=(0, 1))
 
 
+# Warm start of the Gram eigensolve.  Between two solves of a stream G
+# gains one small-rank term, so the previous solve's top-p Ritz basis lies
+# close to the new one, and block subspace iteration from it reaches the
+# Ritz pairs the full eigh would give.  Each iteration shrinks the error of
+# kept direction i by s_{p+1}/s_i: 1e-5 to 2e-3 on the CFD slab streams,
+# where the residuals ‖G uᵢ − sᵢ uᵢ‖ reach their f32 floor (1e-7 to 1e-6
+# of s0) within an iteration and stop falling there.  A residual test
+# alone therefore cannot tell one iteration from three, and a spectrum
+# that decays more slowly needs more: at least _WARM_MIN_ITERS, which
+# shrink an error by (s_{p+1}/s_i)³, then the residual test at ~10× the
+# floor.  A stream that does not meet it within _WARM_MAX_ITERS falls back
+# to the full eigh.
+_WARM_MIN_ITERS = 3
+_WARM_TOL = 1e-5             # max ‖G uᵢ − sᵢ uᵢ‖ over kept i, ÷ s0
+_WARM_MAX_ITERS = 30
+
+
+def _rayleigh_ritz(G: jax.Array, Z: jax.Array):
+    """G's Ritz pairs on span(Z), largest first: (s (p,), U (d, p), G U)."""
+    Q, _ = jnp.linalg.qr(Z)
+    GQ = G @ Q
+    s, W = jnp.linalg.eigh(Q.T @ GQ)         # ascending
+    W = W[:, ::-1]
+    return s[::-1], Q @ W, GQ @ W
+
+
+def _deflated_projection(G: jax.Array, A: jax.Array, s: jax.Array,
+                         U: jax.Array):
+    """(UᵀGU, UᵀAU), (p, p), for G's Ritz pairs (s, U).
+
+    G's entries are of the order of s0, so an f32 product G U carries
+    errors of ~ε·s0 in every column, and the kept directions' Ritz values
+    reach down to ``_REL_TOL``·s0: that error, not the eigensolve, sets how
+    far a direction near the cutoff moves.  Taking U diag(s) Uᵀ out of G
+    entry by entry first leaves D = G − U diag(s) Uᵀ, whose entries are of
+    the order of the residuals, so UᵀGU = diag(s) + UᵀDU and
+    UᵀAU = UᵀGU + Uᵀ(A − G)U come out with errors relative to what they
+    hold (A − G = (Y − X)ᵀX is small where the flow moves little between
+    snapshots)."""
+    D = G - (U * s[None, :]) @ U.T
+    B = jnp.diag(s) + U.T @ (D @ U)
+    return B, B + U.T @ ((A - G) @ U)
+
+
 @partial(jax.jit, static_argnames=("rank",))
-def _gram_operator(G: jax.Array, A: jax.Array, rank: int,
-                   rel_tol=_REL_TOL):
-    """Device half of ``gram_eigs``: (M_r (r, r), #good directions)."""
+def _gram_operator(G: jax.Array, A: jax.Array, basis: jax.Array,
+                   warm: jax.Array, rank: int):
+    """Device half of the Gram route: (UᵀGU (p, p), UᵀAU (p, p), G's
+    top-p Ritz basis U (d, p), warm iterations run, whether they fell
+    back); ``_ritz_eigs`` is the host half.
+
+    With ``warm`` true, block subspace iteration from ``basis`` (the
+    previous solve's Ritz basis), each step one product with G and a
+    Rayleigh-Ritz step, until the kept directions meet the residual test;
+    otherwise, or if they do not within ``_WARM_MAX_ITERS``, the full
+    ``eigh`` (TPU runs only the branch ``lax.cond`` takes).  ``basis`` and
+    ``warm`` have one shape on every call, so one program serves both."""
+    d = G.shape[0]
+    p, r = min(2 * rank, d), min(rank, d)
     with jax.default_matmul_precision(_PRECISION):
-        s, U = jnp.linalg.eigh(G)                # ascending
-        # one subspace-iteration + Rayleigh-Ritz step on the top 2·rank
-        # directions: TPU's eigh leaves eigenvalue errors of ~1e-5·‖G‖,
-        # which a direction at _REL_TOL cannot afford; the small eigh of
-        # QᵀGQ resolves it to the f32 rounding of G itself
-        p = min(2 * rank, G.shape[0])
-        Q, _ = jnp.linalg.qr(G @ U[:, -p:])
-        s, W = jnp.linalg.eigh(Q.T @ G @ Q)
-        s = s[::-1]
-        U = (Q @ W)[:, ::-1]
-        r = min(rank, G.shape[0])
-        s_r, U_r = s[:r], U[:, :r]
-        good = s_r > rel_tol * jnp.maximum(s_r[0], 1e-30)
-        inv = jnp.where(good, 1.0 / jnp.maximum(s_r, 1e-30), 0.0)
-        M = (U_r.T @ A @ U_r) * inv[None, :]
-    return M, jnp.sum(good)
+        def more(c):
+            k, conv = c[0], c[4]
+            return (warm & (k < _WARM_MAX_ITERS)
+                    & ((k < _WARM_MIN_ITERS) | ~conv))
+
+        def iterate(c):
+            k, _s, _U, GU, _conv = c
+            s, U, GU = _rayleigh_ritz(G, GU)
+            res = jnp.linalg.norm(GU[:, :r] - U[:, :r] * s[None, :r], axis=0)
+            return (k + 1, s, U, GU,
+                    jnp.max(res) <= _WARM_TOL * jnp.maximum(s[0], 1e-30))
+
+        k, s, U, _GU, conv = jax.lax.while_loop(
+            more, iterate,
+            (jnp.int32(0), jnp.zeros(p, F32), basis, G @ basis,
+             jnp.bool_(False)))
+
+        def cold():
+            # the full eigh, then one subspace-iteration + Rayleigh-Ritz
+            # step on its top p directions: TPU's eigh leaves eigenvalue
+            # errors of ~1e-5·‖G‖, which a direction at _REL_TOL cannot
+            # afford; the small eigh of QᵀGQ resolves it to the f32
+            # rounding of G itself
+            _, V = jnp.linalg.eigh(G)            # ascending
+            s, U, _ = _rayleigh_ritz(G, G @ V[:, -p:])
+            return s, U
+
+        s, U = jax.lax.cond(conv, lambda: (s, U), cold)
+        B, C = _deflated_projection(G, A, s, U)
+    return B, C, U, k, warm & ~conv
+
+
+def _ritz_eigs(B, C, rank: int, rel_tol: float = _REL_TOL) -> np.ndarray:
+    """Host half of the Gram route, in float64: the eigh of the (p, p)
+    B = UᵀGU, its top ``rank`` directions W_r with s above ``rel_tol``·s0,
+    M_r = W_rᵀ C W_r diag(1/s_r) for C = UᵀAU, and ``_small_eigs`` of it.
+    A p×p eigh on the host costs microseconds and separates the kept
+    directions from the dropped ones in float64, where an f32 eigh of B,
+    whose entries span s0 down to s_p, would leave errors of ~ε·s0."""
+    B, C = np.asarray(B, np.float64), np.asarray(C, np.float64)
+    r = min(rank, B.shape[0])
+    if not (np.isfinite(B).all() and np.isfinite(C).all()):
+        return _small_eigs(np.full((r, r), np.nan), 0)
+    s, W = np.linalg.eigh((B + B.T) / 2)             # ascending
+    s, W = s[::-1][:r], W[:, ::-1][:, :r]
+    good = s > rel_tol * max(s[0], 1e-30)
+    inv = np.where(good, 1.0 / np.maximum(s, 1e-30), 0.0)
+    return _small_eigs((W.T @ C @ W) * inv[None, :], good.sum())
+
+
+def _no_basis(d: int, rank: int) -> jax.Array:
+    """The basis argument of a cold ``_gram_operator`` call."""
+    return jnp.zeros((d, min(2 * rank, d)), F32)
 
 
 def gram_eigs(G: jax.Array, A: jax.Array, rank: int = 8,
@@ -174,8 +273,11 @@ def gram_eigs(G: jax.Array, A: jax.Array, rank: int = 8,
     (anything else is noise-nullspace and would blow up the pseudo-inverse):
     M_r = U_rᵀ A U_r diag(1/s_r);  eig(M_r).  Null directions come back NaN
     — consumers (metrics, tests) filter non-finite entries, so rank padding
-    never reads as (in)stability."""
-    return _small_eigs(*_gram_operator(G, A, rank=rank, rel_tol=rel_tol))
+    never reads as (in)stability.  Always the cold route (the full
+    ``eigh``); ``StreamingDMD`` warm-starts from its previous solve."""
+    B, C, *_ = _gram_operator(G, A, _no_basis(G.shape[0], rank),
+                              jnp.bool_(False), rank=rank)
+    return _ritz_eigs(B, C, rank, rel_tol)
 
 
 def _pad_rows(n: int) -> int:
@@ -423,6 +525,9 @@ class StreamingDMD:
     _buf: list = field(default_factory=list)
     _G: jax.Array | None = None      # (d, d) Gram, lives on device
     _A: jax.Array | None = None      # (d, d) cross-Gram, lives on device
+    # the last Gram solve's top-p Ritz basis (d, p), on device: the next
+    # solve's warm start
+    _basis: jax.Array | None = None
     last_snapshot: np.ndarray | None = None
     n_seen: int = 0
     # eigensolve cache: valid until the next update lands
@@ -432,6 +537,8 @@ class StreamingDMD:
     h2d_transfers: int = 0
     d2h_transfers: int = 0
     device_calls: int = 0
+    warm_solves: int = 0             # Gram solves the warm start served
+    eig_fallbacks: int = 0           # warm starts that fell back to eigh
 
     def _coerce(self, snapshot) -> np.ndarray:
         x = np.asarray(snapshot, np.float32).reshape(-1)[: self.n_features]
@@ -514,30 +621,50 @@ class StreamingDMD:
     def eigenvalues(self) -> np.ndarray:
         """Current DMD eigenvalues.  Cached: a second call with no update
         in between returns the previous solve without touching the device
-        (telemetry re-reads stop re-running ``gram_eigs`` on unchanged
-        G/A — watch ``device_calls`` stand still).  Span
-        ``analysis.stream_eig``: the route taken (``cached``; ``exact`` while
-        the stream has seen at most ``window`` snapshots; ``gram`` past
-        that) and ``n_seen``."""
-        cached = (self._eigs_cache is not None
-                  and self._eigs_seen == self.n_seen)
-        route = ("cached" if cached
-                 else "exact" if self.n_seen <= self.window else "gram")
-        with span("analysis.stream_eig", route=route, n_seen=self.n_seen):
-            if cached:
+        (telemetry re-reads stop re-running the solve on unchanged G/A —
+        watch ``device_calls`` stand still).  Span ``analysis.stream_eig``:
+        the route taken (``cached``; ``exact`` while the stream has seen at
+        most ``window`` snapshots; past that ``warm``, subspace iteration
+        from the previous Gram solve's basis, or ``gram``, the full
+        ``eigh``: the first Gram solve and any warm start that fell back),
+        ``n_seen``, and on the Gram route ``iters`` (warm iterations run)
+        and ``fell_back``."""
+        with span("analysis.stream_eig", n_seen=self.n_seen) as sp:
+            if (self._eigs_cache is not None
+                    and self._eigs_seen == self.n_seen):
+                sp.set_metadata(route="cached")
                 return self._eigs_cache
+            route = "exact"
             if self.n_seen < 3:
                 eigs = np.zeros(1, np.complex64)
-            elif route == "exact":
+            elif self.n_seen <= self.window:
                 snaps = jnp.asarray(np.stack(self._buf, axis=1))
                 self.h2d_transfers += 1
                 self.device_calls += 1
                 eigs, _ = exact_dmd(snaps, rank=self.rank)
                 self.d2h_transfers += 1
             else:
-                self.device_calls += 1
-                eigs = gram_eigs(self._G, self._A, rank=self.rank)
-                self.d2h_transfers += 1
+                eigs, route, iters, fell_back = self._gram_solve()
+                sp.set_metadata(iters=iters, fell_back=fell_back)
+            sp.set_metadata(route=route)
             self._eigs_cache = eigs
             self._eigs_seen = self.n_seen
             return eigs
+
+    def _gram_solve(self) -> tuple[np.ndarray, str, int, bool]:
+        """One ``_gram_operator`` call, warm from the previous Gram solve's
+        basis where there is one; keeps the new basis on the device.
+        Returns (eigenvalues, route, warm iterations run, fell back)."""
+        warm = self._basis is not None
+        basis = self._basis if warm else _no_basis(self.n_features,
+                                                   self.rank)
+        self.device_calls += 1
+        B, C, self._basis, iters, fell_back = _gram_operator(
+            self._G, self._A, basis, jnp.bool_(warm), rank=self.rank)
+        B, C, iters, fell_back = jax.device_get((B, C, iters, fell_back))
+        self.d2h_transfers += 1
+        fell_back = bool(fell_back)
+        self.eig_fallbacks += fell_back
+        self.warm_solves += warm and not fell_back
+        route = "warm" if warm and not fell_back else "gram"
+        return _ritz_eigs(B, C, self.rank), route, int(iters), fell_back

@@ -13,7 +13,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.analysis.dmd import (StreamingDMD, _pad_cols, _pad_rows,
+from repro.analysis.dmd import (_WARM_MAX_ITERS, _WARM_MIN_ITERS,
+                                StreamingDMD, _pad_cols, _pad_rows,
                                 make_dmd_aggregate)
 from repro.runtime.telemetry import span
 from repro.workflow import OperatorPipeline, Session, WorkflowConfig
@@ -206,10 +207,19 @@ def test_stream_spans_carry_rows_padding_bytes_and_route(tmp_path):
         [(1, 0), (2, 2), (3, 4), (1, 1)]
     for a in updates:
         assert a["bytes"] == 2 * a["padded_rows"] * D * 4
-    eigs = [(sp["args"]["route"], sp["args"]["n_seen"]) for sp in spans
-            if sp["name"] == "analysis.stream_eig"]
-    assert eigs == [("exact", 1), ("cached", 1), ("exact", 3), ("cached", 3),
-                    ("gram", 6), ("cached", 6), ("gram", 7), ("cached", 7)]
+    solves = [sp["args"] for sp in spans
+              if sp["name"] == "analysis.stream_eig"]
+    assert [(a["route"], a["n_seen"]) for a in solves] == \
+        [("exact", 1), ("cached", 1), ("exact", 3), ("cached", 3),
+         ("gram", 6), ("cached", 6), ("warm", 7), ("cached", 7)]
+    # the first Gram solve is the full eigh; the next starts from its basis
+    # and converges within the warm iterations
+    gram = [(a["route"], a["iters"], a["fell_back"]) for a in solves
+            if "iters" in a]
+    assert [r for r, _, _ in gram] == ["gram", "warm"]
+    assert gram[0][1:] == (0, False)
+    assert _WARM_MIN_ITERS <= gram[1][1] <= _WARM_MAX_ITERS
+    assert not gram[1][2]
 
 
 def test_untraced_stream_spans_record_nothing_and_change_no_result(tmp_path):
