@@ -47,7 +47,8 @@ from repro.cloud import DEFAULT_CATALOG
 from repro.launch.compile_cache import enable_compile_cache
 from repro.sim.scenario import LoadPhase, Scenario, ScenarioRunner
 from repro.streaming.engine import percentile_sorted
-from repro.workflow import ElasticityConfig, Session, WorkflowConfig
+from repro.workflow import (ElasticityConfig, OperatorPipeline, Session,
+                            WorkflowConfig)
 
 N_RANKS = 4
 FIELD_ELEMS = 256
@@ -152,7 +153,9 @@ def _run_mode_wall(mode: str, smoke: bool) -> dict:
 
     payload = np.zeros(FIELD_ELEMS, np.float32)
     phase_windows: list[tuple[str, float, float]] = []
-    with Session(cfg, analyze=analyze) as sess:
+    pipeline = OperatorPipeline(granularity="batch").map(
+        "analyze", analyze, ordering="ordered")
+    with Session(cfg, pipeline=pipeline) as sess:
         h = sess.open_field("load", shape=(FIELD_ELEMS,))
         step = 0
         for name, dur, rate in _profile(smoke):

@@ -5,8 +5,8 @@ pipelines differing ONLY in the work stage's ordering contract:
 
   ordered     Map(ordering="ordered"): every micro-batch of the stream runs
               under the per-stream ordering ticket on its sticky executor —
-              exactly-sequenced, hence serial per stream (the legacy
-              AnalysisDAG behavior).
+              exactly-sequenced, hence serial per stream (what a plain
+              per-batch callback gets).
   unordered   Map(ordering="unordered"): the compiled plan has no ordered
               suffix, so the engine spreads the stream's micro-batches
               across ALL executors with no ticket — intra-stream parallel.

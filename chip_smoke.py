@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Chip smoke test: the paper's CFD -> broker -> DMD workflow on one TPU.
 
-Runs the ``benchmarks/end_to_end.py`` deployment once, end to end, through
+Runs the paper's Fig 6 deployment once, end to end, through
 the entry points a user calls (``Session``, ``OperatorPipeline``,
 ``StreamingDMD``, ``batched_window_dmd``):
 
@@ -356,7 +356,7 @@ def main() -> int:
           flush=True)
 
     from repro.sim.cfd import CFDConfig
-    # the benchmarks/end_to_end.py deployment
+    # the paper's Fig 6 deployment
     cfg = CFDConfig(nx=192, nz=96, n_regions=16, pressure_iters=50)
     t0 = time.perf_counter()
     try:

@@ -20,7 +20,7 @@ from repro.models import transformer as T
 from repro.models.modules import materialize
 from repro.models.steps import make_train_step
 from repro.optim import adamw
-from repro.workflow import Session, WorkflowConfig
+from repro.workflow import OperatorPipeline, Session, WorkflowConfig
 
 # ---- 1. the "HPC" side: a (tiny) LM training job --------------------------
 cfg = C.get("starcoder2-3b").reduced()
@@ -44,7 +44,9 @@ def analyze(key, records):
         sd.update(np.asarray(r.payload).reshape(-1)[: cfg.tap_snapshot_dim])
     return unit_circle_distance(sd.eigenvalues())
 
-session = Session(workflow, analyze=analyze)
+pipeline = OperatorPipeline(granularity="batch").map("analyze", analyze,
+                                                     ordering="ordered")
+session = Session(workflow, pipeline=pipeline)
 streamer = TapStreamer(session, n_regions=N_REGIONS)
 
 # ---- 3. run the cross-ecosystem workflow -----------------------------------

@@ -63,7 +63,7 @@ broker = broker_connect(eps, n_producers=N_REGIONS,
                         cfg=BrokerConfig(compress="int8+zstd"),
                         plan=GroupPlan(N_REGIONS, 1, 4))
 engine = StreamEngine([e.handle for e in eps],
-                      dmd_analyzer(cfg.tap_snapshot_dim),
+                      dmd_analyzer(cfg.tap_snapshot_dim).compile(),
                       n_executors=4, trigger_interval=1.0)
 streamer = TapStreamer(broker, n_regions=N_REGIONS)
 

@@ -28,12 +28,13 @@ from repro.models.modules import materialize
 from repro.models.steps import make_train_step
 from repro.optim import adamw
 from repro.runtime.fault import FailureDetector
-from repro.workflow import Session, WorkflowConfig
+from repro.workflow import OperatorPipeline, Session, WorkflowConfig
 from repro.analysis.dmd import StreamingDMD
 from repro.analysis.metrics import unit_circle_distance
 
 
-def dmd_analyzer(n_features: int):
+def dmd_analyzer(n_features: int) -> OperatorPipeline:
+    """Per-region online DMD stability, one ordered stage per micro-batch."""
     states: dict = {}
 
     def analyze(key, records):
@@ -44,7 +45,8 @@ def dmd_analyzer(n_features: int):
                          sorted(records, key=lambda r: r.step)])
         return unit_circle_distance(sd.eigenvalues())
 
-    return analyze
+    return OperatorPipeline(granularity="batch").map("analyze", analyze,
+                                                     ordering="ordered")
 
 
 def build(arch: str, preset: str, batch: int, seq: int, microbatches: int,
@@ -98,7 +100,7 @@ def main(argv=None):
                                   compress="int8+zstd", trigger_interval=1.0,
                                   n_executors=args.regions)
         session = Session(workflow,
-                          analyze=dmd_analyzer(cfg.tap_snapshot_dim))
+                          pipeline=dmd_analyzer(cfg.tap_snapshot_dim))
         streamer = TapStreamer(session, n_regions=args.regions)
 
     det = FailureDetector(timeout_s=30.0)

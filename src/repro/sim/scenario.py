@@ -38,7 +38,7 @@ from repro.cloud.nodes import READY
 from repro.runtime.clock import VirtualClock
 from repro.runtime.wal import WalStore
 from repro.streaming.engine import percentile_sorted
-from repro.streaming.operators import WindowPane
+from repro.streaming.operators import OperatorPipeline, WindowPane
 from repro.tenancy import TENANT_COUNTERS, closure_errors
 from repro.workflow.config import WorkflowConfig
 from repro.workflow.session import Session
@@ -417,13 +417,12 @@ class ScenarioRunner:
             # operator-level trace events: window fires / late drops / sinks
             emit("op", event=kind, **d)
 
-        if sc.operators is not None:
-            sess = Session(sc.workflow, pipeline=sc.operators(), clock=clock,
-                           wal=wal, checkpoints=ckpt_store)
-            sess.exec_plan.on_event = op_emit
-        else:
-            sess = Session(sc.workflow, analyze=analyze, clock=clock,
-                           wal=wal, checkpoints=ckpt_store)
+        pipeline = sc.operators() if sc.operators is not None else \
+            OperatorPipeline(granularity="batch").map("analyze", analyze,
+                                                      ordering="ordered")
+        sess = Session(sc.workflow, pipeline=pipeline, clock=clock,
+                       wal=wal, checkpoints=ckpt_store)
+        sess.exec_plan.on_event = op_emit
 
         # every live reference routes through the box: kill_session swaps
         # the session (and its field handle) under the load loop's feet
@@ -652,7 +651,7 @@ class ScenarioRunner:
             trace.summary["controller_actions"] = act_counts
         if sess.provisioner is not None:
             trace.summary["provisioning"] = sess.provisioner.summary()
-        if sess.exec_plan is not None:
+        if sc.operators is not None:
             trace.summary["windows"] = sess.exec_plan.accounting()
             # content oracle: per-sink, per-key ordered values (no times)
             trace.summary["sink_digest"] = sink_digest(sess.exec_plan)

@@ -4,7 +4,9 @@ Implements the paper's Cloud pipeline (Fig 2/3): endpoints feed per-stream
 micro-batches (trigger-interval windows, like Spark DStreams); micro-batches
 of one stream form partitions of an RDD-like unit of work; a fixed subset of
 executors owns each endpoint's partitions (the paper's 16:1:16 mapping) and
-pipes each partition to the analysis function exactly once (rdd.pipe); a
+pipes each partition exactly once (rdd.pipe) through the compiled operator
+``ExecutionPlan`` (repro.streaming.operators) — the engine's only unit of
+work; a per-batch callback is a one-stage, batch-granularity plan.  A
 collector gathers results (rdd.collect) with generation->analysis latency.
 
 Beyond the paper (Spark gave these for free; we implement them):
@@ -22,14 +24,13 @@ Beyond the paper (Spark gave these for free; we implement them):
                       snapshot (per-executor queues, rolling latency
                       percentiles, executor-seconds) consumed by
                       ``repro.runtime.telemetry``,
-  * plan-aware dispatch — ``attach_plan`` installs a compiled operator
-                      ``ExecutionPlan`` (repro.streaming.operators): its
-                      order-insensitive prefix runs before/without the
-                      per-stream ordering ticket with partitions spread
-                      across executors (intra-stream parallelism), while
-                      the ordered suffix keeps the exact-sequence
-                      guarantee; ``drain_and_stop`` fires still-open
-                      window panes once every partition has completed.
+  * plan-aware dispatch — the plan's order-insensitive prefix runs
+                      before/without the per-stream ordering ticket with
+                      partitions spread across executors (intra-stream
+                      parallelism), while the ordered suffix keeps the
+                      exact-sequence guarantee; ``drain_and_stop`` fires
+                      still-open window panes once every partition has
+                      completed.
 """
 from __future__ import annotations
 
@@ -37,7 +38,7 @@ import queue
 import threading
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable
+from typing import Any
 
 from repro.core.records import StreamRecord
 from repro.runtime.clock import Clock, ensure_clock
@@ -145,20 +146,7 @@ class _Executor(threading.Thread):
     def _process(self, mb: MicroBatch, clock) -> None:
         """Analyse one micro-batch and hand its Result to the engine."""
         eng = self.engine
-        plan = eng.plan
-        if plan is None:
-            self.waiting = True
-            eng._await_turn(mb)    # per-stream order even across steals
-            self.waiting = False
-            self.t_busy_since = clock.now()
-            if self.slowdown:
-                clock.sleep(self.slowdown)
-            try:
-                value = eng.analyze_fn(mb.stream_key, mb.records)
-            except Exception as e:  # analysis failure != engine failure
-                value = e
-        else:
-            value = self._run_plan(plan, mb, clock)
+        value = self._run_plan(eng.plan, mb, clock)
         tmin = min((r.t_generated for r in mb.records), default=mb.t_created)
         by_tenant: dict[str, tuple[int, float]] = {}
         for r in mb.records:
@@ -176,13 +164,16 @@ class _Executor(threading.Thread):
         (and without) the stream's ordering ticket — that's what lets
         micro-batches of ONE stream proceed concurrently on many executors —
         then the ordered suffix (if any) under the ticket, exactly
-        sequenced.  A plan with no ordered stages never takes the ticket."""
+        sequenced.  A plan with no ordered stages never takes the ticket.
+        A straggler's ``slowdown`` lands just before the first stage this
+        executor runs: ahead of the prefix, or after the ticket when the
+        plan is all suffix."""
         eng = self.engine
         self.t_busy_since = clock.now()
-        if self.slowdown:
-            clock.sleep(self.slowdown)
         pre_out = None
         if plan.pre_stages:
+            if self.slowdown:
+                clock.sleep(self.slowdown)
             try:
                 # seq feeds the plan's in-order frontier: window watermarks
                 # advance only over the contiguous per-stream prefix, so
@@ -204,6 +195,8 @@ class _Executor(threading.Thread):
         eng._await_turn(mb)
         self.waiting = False
         self.t_busy_since = clock.now()
+        if pre_out is None and self.slowdown:
+            clock.sleep(self.slowdown)
         try:
             return plan.run_post(mb.stream_key, pre_out, mb.records)
         except Exception as e:
@@ -218,12 +211,13 @@ _POISON = MicroBatch(stream_key="__poison__", records=[])
 
 
 class StreamEngine:
-    def __init__(self, endpoints: list, analyze_fn: Callable,
-                 n_executors: int, *, trigger_interval: float = 3.0,
+    def __init__(self, endpoints: list, plan, n_executors: int, *,
+                 trigger_interval: float = 3.0,
                  min_batch: int = 2, clock: Clock | None = None,
                  order_wait_s: float = _ORDER_WAIT_S,
                  shuffle_partitions: int | None = None):
-        """endpoints: Endpoint handles (drain API).  analyze_fn(key, records).
+        """endpoints: Endpoint handles (drain API).  plan: the compiled
+        operator ``ExecutionPlan`` every micro-batch runs through.
 
         ``min_batch``: a stream's drained records are held until at least
         this many accumulate (so the analyze path sees real micro-batches —
@@ -243,8 +237,6 @@ class StreamEngine:
         partition->executor ownership replaces producer-stream ownership,
         and ordering tickets are issued per partition."""
         self.endpoints = endpoints
-        self.analyze_fn = analyze_fn
-        self.plan = None               # compiled operator ExecutionPlan
         self.trigger_interval = trigger_interval
         self.min_batch = min_batch
         self.order_wait_s = order_wait_s
@@ -270,6 +262,7 @@ class StreamEngine:
         self._done_seq: dict[str, int] = {}    # stream -> completed prefix
         self.order_timeouts = 0                # broken-chain escapes (rare)
         self.rebalances = 0
+        self.attach_plan(plan)
         # executor-seconds integral (elasticity cost accounting)
         self._exec_secs = 0.0
         self._exec_t = self.clock.now()
@@ -281,55 +274,44 @@ class StreamEngine:
         self._driver.start()
 
     @classmethod
-    def from_config(cls, cfg, endpoints: list, analyze_fn: Callable, *,
-                    plan=None, clock: Clock | None = None) -> "StreamEngine":
+    def from_config(cls, cfg, endpoints: list, plan, *, groups=None,
+                    clock: Clock | None = None) -> "StreamEngine":
         """Build from a ``repro.workflow.WorkflowConfig`` (duck-typed here to
         keep streaming← workflow import-free).  ``n_executors=None`` falls
-        back to the plan's groups × executors_per_group — the paper's
-        16:1:16 operating point."""
+        back to the ``GroupPlan`` ``groups``' groups × executors_per_group —
+        the paper's 16:1:16 operating point."""
         n_exec = cfg.n_executors
         if n_exec is None:
-            n_exec = plan.n_executors if plan is not None \
+            n_exec = groups.n_executors if groups is not None \
                 else max(1, len(endpoints)) * cfg.executors_per_group
-        return cls(endpoints, analyze_fn, n_executors=n_exec,
+        return cls(endpoints, plan, n_executors=n_exec,
                    trigger_interval=cfg.trigger_interval,
                    min_batch=cfg.min_batch, clock=clock,
                    order_wait_s=getattr(cfg, "order_wait_s", _ORDER_WAIT_S),
                    shuffle_partitions=getattr(cfg, "shuffle_partitions",
                                               None))
 
-    def attach_dag(self, dag: Callable) -> None:
-        """Session-driven rewiring: route every micro-batch through an
-        ``AnalysisDAG`` (or any ``(stream_key, records) -> value`` callable).
-        Takes effect for the next dispatched partition — executors look up
-        ``analyze_fn`` per call."""
-        self.plan = None
-        self.analyze_fn = dag
-
     def attach_plan(self, plan) -> None:
         """Route every micro-batch through a compiled operator
         ``ExecutionPlan`` (see ``repro.streaming.operators``).  Dispatch
-        becomes plan-aware: plans with an order-insensitive prefix get their
+        is plan-aware: plans with an order-insensitive prefix get their
         partitions spread across executors (intra-stream parallelism, capped
         by the plan's parallelism hint) instead of sticky-assigned, and the
         ordering ticket is only taken for the plan's ordered suffix.
 
-        Attaching mid-run aligns the plan's watermark frontier with the
-        engine's continuing per-stream seq counters — a fresh frontier
-        expecting seq 0 would park every future batch as pending and stall
-        window firing until drain.
+        Attaching mid-run replaces the plan and aligns its watermark
+        frontier with the engine's continuing per-stream seq counters — a
+        fresh frontier expecting seq 0 would park every future batch as
+        pending and stall window firing until drain.
 
         With ``shuffle_partitions`` configured, a plan that compiles to a
         shuffle edge (source KeyBy, record granularity) switches to keyed-
         shuffle dispatch; plans without one keep producer partitioning."""
-        enable = getattr(plan, "enable_shuffle", None)
-        if (self.shuffle_partitions is not None and enable is not None
-                and getattr(plan, "shuffle_op", None) is not None):
-            enable(self.shuffle_partitions)
-        seed = getattr(plan, "seed_frontier", None)
-        if seed is not None:
-            with self._tlock:
-                seed(dict(self._next_seq))
+        if self.shuffle_partitions is not None \
+                and plan.shuffle_op is not None:
+            plan.enable_shuffle(self.shuffle_partitions)
+        with self._tlock:
+            plan.seed_frontier(dict(self._next_seq))
         self.plan = plan
 
     # ---- per-stream ordering tickets ------------------------------------
@@ -522,7 +504,7 @@ class StreamEngine:
         alive = self._alive()
         if not alive:
             return None
-        hint = self.plan.parallelism if self.plan is not None else None
+        hint = self.plan.parallelism
         if hint is not None and hint < len(alive):
             alive = sorted(alive, key=lambda e: e.q.qsize())[:hint]
         return min(alive, key=lambda e: e.q.qsize())
@@ -559,8 +541,7 @@ class StreamEngine:
             if mb is _POISON:          # dying executor: hand it back
                 victim.q.put(_POISON)
                 continue
-            if (self.plan is not None and self.plan.parallel_dispatch
-                    and not getattr(self.plan, "shuffled", False)):
+            if self.plan.parallel_dispatch and not self.plan.shuffled:
                 # parallel-dispatch plans have no sticky run to migrate:
                 # batches of one stream are already spread, so steal just
                 # the head partition.  Shuffled plans DO have sticky runs
@@ -609,7 +590,7 @@ class StreamEngine:
         n = 0
         now = self.clock.now()
         plan = self.plan
-        shuffled = plan is not None and getattr(plan, "shuffled", False)
+        shuffled = plan.shuffled
         with span("engine.trigger") as sp, self._tlock:
             for ep in self.endpoints:
                 for key in ep.stream_keys():
@@ -630,8 +611,7 @@ class StreamEngine:
                         or now - self._hold_t[key] >= self.trigger_interval)
                 if not ripe:
                     continue
-                parallel = (not shuffled and plan is not None
-                            and plan.parallel_dispatch)
+                parallel = not shuffled and plan.parallel_dispatch
                 ex = self._pick_parallel() if parallel \
                     else self._pick_executor(key)
                 if ex is None:
@@ -713,10 +693,8 @@ class StreamEngine:
                     "latency_window_n": len(tl),
                     "latency_p50": percentile_sorted(tl, 0.50),
                     "latency_p99": percentile_sorted(tl, 0.99)}
-        batch_agg = self.plan.batch_stats() if self.plan is not None else {}
-        shuffle_n = self.plan.shuffle_partitions \
-            if self.plan is not None and getattr(self.plan, "shuffled", False) \
-            else None
+        batch_agg = self.plan.batch_stats()
+        shuffle_n = self.plan.shuffle_partitions   # None unless shuffled
         return {"executors": execs,
                 "tenants": tenants,
                 "shuffle_partitions": shuffle_n,
@@ -764,10 +742,9 @@ class StreamEngine:
             e.q.put(_POISON)
         for e in survivors:          # results must be collected before return
             self.clock.join(e, timeout=5.0)
-        if self.plan is not None:
-            # every partition is done: fire still-open window panes through
-            # the rest of the graph (single-threaded, deterministic order)
-            self.plan.flush()
+        # every partition is done: fire still-open window panes through
+        # the rest of the graph (single-threaded, deterministic order)
+        self.plan.flush()
 
     # ---- exactly-once recovery -------------------------------------------
     def kill(self) -> None:

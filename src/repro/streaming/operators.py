@@ -1,12 +1,10 @@
 """Typed stream operators — the Cloud analysis layer as a real dataflow API.
 
 The paper's Cloud side is a distributed stream-processing service (§4: Flink
-jobs over broker streams), but the first DAG implementation here was a bare
-``(stream_key, value) -> value`` callback graph with no notion of windows,
-keys, or per-stage ordering — and the engine serialized every stage of every
-stream behind one ordering ticket.  This module replaces that with a typed
+jobs over broker streams).  This module is its analysis layer: a typed
 operator model in the spirit of openPMD/ADIOS2 streaming pipelines and
-Wilkins-style declarative in-situ graphs:
+Wilkins-style declarative in-situ graphs, and the only way to attach
+analysis to the ``StreamEngine``:
 
 * :class:`Map` / :class:`Filter`   — per-element transforms,
 * :class:`KeyBy`                   — re-key the stream (fan records of many
@@ -27,10 +25,12 @@ compile` lowers the graph to an :class:`ExecutionPlan` the
 ``unordered``/``keyed`` with no ``ordered`` ancestor) runs *before and
 without* the stream's ordering ticket, so micro-batches of ONE stream are
 analyzed concurrently by many executors; the ordered suffix (if any) keeps
-today's exactly-sequenced guarantee.  ``lower_dag`` compiles a legacy
-:class:`repro.streaming.dag.AnalysisDAG` onto the same plan machinery (all
-stages ordered, batch granularity), which is how the old ``Pipeline`` API
-keeps working unchanged.
+the exactly-sequenced guarantee.  A per-micro-batch callback ``fn(key,
+records)`` is the one-stage plan
+``OperatorPipeline(granularity="batch").map("analyze", fn,
+ordering="ordered")``: the engine's ``Result.value`` is fn's return value
+(``None`` when it returns None, the exception when it raises), in sticky,
+ticketed per-stream order.
 
 Window state lives in the plan (shared across executors, striped per-key
 locks), NOT in any executor thread — so elasticity-driven steals,
@@ -251,7 +251,7 @@ class BatchAggregate(Operator):
 class Sink(Operator):
     """Terminal collection point: appends ``(key, value, t)`` with the
     session clock's now() — never wall time — and passes the element through
-    (sinks may sit mid-chain, like legacy DAG stage sinks)."""
+    (sinks may sit mid-chain)."""
 
     def __init__(self, name: str, *, ordering: str = UNORDERED):
         super().__init__(name, ordering=ordering)
@@ -594,7 +594,8 @@ class ExecutionPlan:
     ``granularity`` selects what a source element is: ``"record"`` explodes
     a micro-batch into one element per ``StreamRecord`` (event time =
     ``t_generated``); ``"batch"`` feeds the whole records list as one
-    element (the legacy ``AnalysisDAG`` semantics used by ``lower_dag``).
+    element, and the source stage's output becomes the partition's primary
+    value (see :meth:`_run_batch_source`).
     """
 
     def __init__(self, ops: dict[str, Operator], downstream: dict[str, list[str]],
@@ -894,9 +895,9 @@ class ExecutionPlan:
 
     def _run_batch_source(self, elem: Element, allowed: set | None,
                           boundary: list | None, defer_fire: bool = False):
-        """Batch-granularity source, capturing its output as the primary
-        value (legacy ``AnalysisDAG.__call__`` returned exactly this) —
-        in whichever phase the source landed."""
+        """Batch-granularity source, capturing its first output's value as
+        the primary value (None when it emits nothing) — in whichever phase
+        the source landed."""
         op = self.ops[self.source]
         if defer_fire and isinstance(op, _Window):
             op.ingest(elem)
@@ -908,14 +909,14 @@ class ExecutionPlan:
         return outs[0].value if outs else None
 
     def _primary(self, key: str, records: list):
-        """The engine ``Result.value`` for this partition: record count for
-        record-granularity plans (the batch-source output overrides it in
-        :meth:`run_post` for legacy plans)."""
+        """The engine ``Result.value`` for this partition: the record count
+        (a batch-granularity source's output overrides it, see
+        :meth:`_run_batch_source`)."""
         return len(records)
 
     def __call__(self, key: str, records: list):
-        """Whole graph inline (both phases) — usable directly as an
-        ``analyze_fn`` or for single-threaded tests."""
+        """Whole graph inline (both phases), as one executor would run it
+        with no concurrency — for single-threaded callers and tests."""
         if self._pre:
             pre_out = self.run_pre(key, records)
             if not self._post:
@@ -1004,8 +1005,7 @@ class ExecutionPlan:
 # ---------------------------------------------------------------------------
 
 class OperatorPipeline:
-    """Fluent builder for operator graphs (the successor of the legacy
-    ``workflow.Pipeline`` stage/then/branch verbs):
+    """Fluent builder for operator graphs:
 
         pipe = (OperatorPipeline()
                 .key_by("by_field", lambda k, r: k.split("/")[0])
@@ -1121,36 +1121,3 @@ class OperatorPipeline:
         return ExecutionPlan(self._ops, self._down, self._source, clock=clock,
                              granularity=granularity or self.granularity)
 
-
-# ---------------------------------------------------------------------------
-# Legacy lowering
-# ---------------------------------------------------------------------------
-
-class _DagStageOp(Operator):
-    """One legacy ``AnalysisDAG`` stage as an (ordered, batch-granularity)
-    operator: run the callback, record non-None output in the DAG's own sink
-    (so ``dag.results()`` keeps working), fan out."""
-
-    def __init__(self, name: str, fn, dag):
-        super().__init__(name, ordering=ORDERED)
-        self.fn = fn
-        self.dag = dag
-
-    def process(self, elem: Element) -> list[Element]:
-        out = self.fn(elem.key, elem.value)
-        if out is None:
-            return []
-        self.dag.record(self.name, elem.key, out)
-        return [Element(elem.key, out, elem.t_event)]
-
-
-def lower_dag(dag, clock: Clock | None = None) -> ExecutionPlan:
-    """Compile a legacy :class:`repro.streaming.dag.AnalysisDAG` onto the
-    operator machinery: every stage ordered, whole-micro-batch elements,
-    sink values landing in the DAG's own per-stage sinks — byte-identical
-    stage results, same sticky per-stream scheduling."""
-    ops = {name: _DagStageOp(name, stage.fn, dag)
-           for name, stage in dag.stages.items()}
-    down = {name: list(stage.downstream) for name, stage in dag.stages.items()}
-    return ExecutionPlan(ops, down, dag.source, clock=clock,
-                         granularity="batch")

@@ -5,9 +5,8 @@ Public surface:
 * :class:`WorkflowConfig` — one validated config (topology + endpoint +
   broker + engine knobs) with a lossless ``to_dict``/``from_dict``.
 * :class:`Session` — context manager owning endpoint creation, broker
-  construction, engine/DAG lifecycle, and ordered teardown.
+  construction, engine/plan lifecycle, and ordered teardown.
 * :class:`FieldHandle` — typed producer handle (``write``/``write_batch``).
-* :class:`Pipeline` — fluent builder compiling to an ``AnalysisDAG``.
 * :class:`ElasticityConfig` — the control-plane knob block; with
   ``enabled=True`` the Session owns a telemetry bus + ElasticController
   that holds the p99 QoS target by scaling executors, adapting wire batch
@@ -20,9 +19,10 @@ The analysis surface is the **stream-operator API**
 ordering contract (``ordered`` | ``unordered`` | ``keyed``) and a
 parallelism hint, compiled to an :class:`ExecutionPlan` the engine honors —
 order-insensitive stages run intra-stream parallel, windows hold keyed
-state with snapshot/restore.  The older :class:`Pipeline`/``AnalysisDAG``
-callback API still works as a deprecated shim that compiles onto the same
-operators.
+state with snapshot/restore.  It is the one way to attach analysis: a
+per-micro-batch callback is a one-stage plan,
+``OperatorPipeline(granularity="batch").map("analyze", fn,
+ordering="ordered")``.
 
 The paper's Listing 1.1 C API (``broker_connect``/``broker_init``/
 ``broker_write``/``broker_finalize`` in :mod:`repro.core.api`) is kept as a
@@ -34,10 +34,9 @@ from repro.streaming.operators import (Aggregate, ExecutionPlan, Filter,
                                        SlidingWindow, TumblingWindow,
                                        WindowPane)
 from repro.workflow.config import WorkflowConfig
-from repro.workflow.pipeline import Pipeline
 from repro.workflow.session import FieldHandle, Session
 
-__all__ = ["WorkflowConfig", "Session", "FieldHandle", "Pipeline",
+__all__ = ["WorkflowConfig", "Session", "FieldHandle",
            "ElasticityConfig", "OperatorPipeline", "ExecutionPlan",
            "Map", "Filter", "KeyBy", "TumblingWindow", "SlidingWindow",
            "Aggregate", "Sink", "WindowPane"]

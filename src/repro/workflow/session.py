@@ -3,16 +3,16 @@
 One object replaces the seed's four hand-wired call sites:
 
     with Session(WorkflowConfig(n_producers=8, n_groups=2),
-                 analyze=my_analyzer) as sess:
+                 pipeline=my_pipeline) as sess:
         vel = sess.open_field("velocity", shape=(256,))
         for s in range(steps):
             vel.write(s, field, rank=r)            # or write_batch(...)
     panel = sess.results()                         # after ordered teardown
 
 ``Session`` owns endpoint creation (per the config's transport), broker
-construction, engine + DAG lifecycle, and ordered teardown —
+construction, engine + operator-plan lifecycle, and ordered teardown —
 ``broker.finalize()`` (drain producer queues onto the endpoints) then
-``engine.drain_and_stop()`` (drain endpoints through the analyzers) then
+``engine.drain_and_stop()`` (drain endpoints through the plan) then
 transport close.  :class:`FieldHandle` is the typed producer-side handle the
 paper's free-floating ``broker_ctx`` grew into: dtype-coercing,
 shape-checking, and batch-aware (``write_batch`` ships all regions of a
@@ -22,7 +22,6 @@ field as one aggregated queue item per group ⇒ ≤ one wire frame per
 from __future__ import annotations
 
 import threading
-import warnings
 
 import numpy as np
 
@@ -34,14 +33,11 @@ from repro.runtime.fault import FailureDetector
 from repro.runtime.recovery import RecoverySupervisor
 from repro.runtime.telemetry import TelemetryBus
 from repro.runtime.wal import FileWalStore, SeqLedger, WalStore
-from repro.streaming.dag import AnalysisDAG
 from repro.streaming.endpoint import make_endpoint, make_endpoints
 from repro.streaming.engine import StreamEngine
-from repro.streaming.operators import (ExecutionPlan, OperatorPipeline,
-                                       lower_dag)
+from repro.streaming.operators import ExecutionPlan, OperatorPipeline
 from repro.tenancy import merge_counts
 from repro.workflow.config import WorkflowConfig
-from repro.workflow.pipeline import Pipeline
 
 
 class RestoreTopologyError(ValueError):
@@ -156,10 +152,10 @@ class FieldHandle:
 
 
 class Session:
-    """Context manager owning broker → endpoint → engine → DAG wiring."""
+    """Context manager owning broker → endpoint → engine → plan wiring."""
 
     def __init__(self, config: WorkflowConfig | None = None, *,
-                 endpoints: list | None = None, analyze=None, pipeline=None,
+                 endpoints: list | None = None, pipeline=None,
                  clock: Clock | None = None, wal: WalStore | None = None,
                  checkpoints=None, ledger: SeqLedger | None = None,
                  _paused: bool = False):
@@ -226,7 +222,6 @@ class Session:
                              wal=self._wal, paused=_paused,
                              tenants=self.tenants)
         self.engine: StreamEngine | None = None
-        self.dag: AnalysisDAG | None = None
         self.exec_plan: ExecutionPlan | None = None   # compiled operator plan
         # control plane (built lazily with the engine when elasticity is on)
         self.telemetry: TelemetryBus | None = None
@@ -243,8 +238,6 @@ class Session:
         try:
             if pipeline is not None:
                 self.attach_pipeline(pipeline)
-            elif analyze is not None:
-                self.attach_analyzer(analyze)
         except Exception:   # don't leak sender threads / loopback sockets
             self.close()
             raise
@@ -279,58 +272,29 @@ class Session:
             self.telemetry.endpoints.append(ep.handle)
         return i
 
-    def attach_analyzer(self, fn) -> StreamEngine:
-        """Point the engine at ``fn(stream_key, records)`` (created lazily
-        on first attach; swapped in place afterwards)."""
-        if self.engine is None:
-            self.engine = StreamEngine.from_config(
-                self.config, self._handles(), fn, plan=self.plan,
-                clock=self.clock)
-            self._start_control_plane()
-        else:
-            self.engine.attach_dag(fn)      # also detaches any operator plan
-        self.exec_plan = None               # stale sinks must not shadow fn
-        self.dag = None
-        return self.engine
-
-    def attach_pipeline(self, pipeline):
-        """Route every micro-batch through an analysis pipeline.
-
-        Accepts the stream-operator API — an :class:`OperatorPipeline`
-        (compiled here against the Session clock) or a prebuilt
-        :class:`ExecutionPlan` — and, deprecated, the legacy
-        :class:`Pipeline` / :class:`AnalysisDAG`, which are lowered onto the
-        same operator machinery (``lower_dag``): identical stage results,
-        ``dag.results()`` keeps working, sink timestamps come from the
-        Session clock.  Returns the legacy DAG for legacy inputs (API
-        compatibility), the compiled plan otherwise."""
-        legacy = None
+    def attach_pipeline(self, pipeline) -> ExecutionPlan:
+        """Route every micro-batch through an operator pipeline: an
+        :class:`OperatorPipeline` (compiled here against the Session clock)
+        or a prebuilt :class:`ExecutionPlan`.  The first attach builds the
+        engine; a later one replaces the plan on the live engine.  Returns
+        the compiled plan."""
         if isinstance(pipeline, OperatorPipeline):
             plan = pipeline.compile(clock=self.clock)
-            self.dag = None                 # drop any stale legacy sinks
         elif isinstance(pipeline, ExecutionPlan):
             plan = pipeline
             plan.bind_clock(self.clock)
-            self.dag = None
         else:
-            warnings.warn(
-                "Pipeline/AnalysisDAG are deprecated: build an "
-                "OperatorPipeline (repro.streaming.operators) with typed "
-                "operators and per-stage ordering contracts instead",
-                DeprecationWarning, stacklevel=2)
-            legacy = pipeline.compile() if isinstance(pipeline, Pipeline) \
-                else pipeline
-            legacy.bind_clock(self.clock)
-            plan = lower_dag(legacy, clock=self.clock)
-            self.dag = legacy
+            raise TypeError(f"attach_pipeline needs an OperatorPipeline or "
+                            f"an ExecutionPlan, got {type(pipeline).__name__}")
         if self.engine is None:
             self.engine = StreamEngine.from_config(
-                self.config, self._handles(), plan, plan=self.plan,
+                self.config, self._handles(), plan, groups=self.plan,
                 clock=self.clock)
             self._start_control_plane()
-        self.engine.attach_plan(plan)
+        else:
+            self.engine.attach_plan(plan)
         self.exec_plan = plan
-        return legacy if legacy is not None else plan
+        return plan
 
     def _start_control_plane(self) -> None:
         """With ``elasticity.enabled``, the Session owns the closed loop:
@@ -417,17 +381,13 @@ class Session:
         merge_counts(self._tenants_base, stats.tenants)
 
     def results(self, stage: str | None = None) -> list:
-        """Engine results; with ``stage``, a legacy DAG stage's sink or an
-        operator plan's :class:`Sink` results."""
+        """Engine results; with ``stage``, the attached operator plan's
+        :class:`Sink` results."""
         if stage is not None:
-            if self.dag is not None:
-                # legacy pipeline: every stage has a DAG sink, and an
-                # unknown stage raises KeyError exactly as the old API did
-                return self.dag.results(stage)
-            if self.exec_plan is not None:
-                return self.exec_plan.results(stage)
-            raise ValueError("no pipeline attached; results(stage=...) "
-                             "needs attach_pipeline()")
+            if self.exec_plan is None:
+                raise ValueError("no pipeline attached; results(stage=...) "
+                                 "needs attach_pipeline()")
+            return self.exec_plan.results(stage)
         return self.engine.collect() if self.engine is not None else []
 
     def latency_stats(self) -> dict:

@@ -1,23 +1,14 @@
-"""In-situ analysis DAGs (paper §6 future work): multi-stage graphs running
-inside the stream engine, with filtering alert sinks."""
+"""In-situ analysis DAGs (paper §6 future work): multi-stage operator
+graphs running inside the stream engine, with filtering alert sinks."""
 import numpy as np
-import pytest
 
 from repro.analysis.dmd import StreamingDMD
 from repro.analysis.metrics import unit_circle_distance
 from repro.core.broker import Broker, BrokerConfig
 from repro.core.grouping import GroupPlan
-from repro.streaming.dag import AnalysisDAG, Stage
 from repro.streaming.endpoint import make_endpoints
 from repro.streaming.engine import StreamEngine
-
-
-def test_cycle_rejected():
-    with pytest.raises(ValueError, match="cycle"):
-        AnalysisDAG([Stage("a", lambda k, v: v, ["b"]),
-                     Stage("b", lambda k, v: v, ["a"])], source="a")
-    with pytest.raises(ValueError, match="unknown downstream"):
-        AnalysisDAG([Stage("a", lambda k, v: v, ["zz"])], source="a")
+from repro.streaming.operators import OperatorPipeline
 
 
 def test_dag_in_engine_with_alerting():
@@ -42,15 +33,15 @@ def test_dag_in_engine_with_alerting():
             return ("UNSTABLE", key, score)
         return None                   # filtered: no sink entry, no fan-out
 
-    dag = AnalysisDAG(
-        [Stage("dmd", dmd_stage, ["stability"]),
-         Stage("stability", stability_stage, ["alert"]),
-         Stage("alert", alert_stage)],
-        source="dmd")
+    plan = (OperatorPipeline(granularity="batch")
+            .map("dmd", dmd_stage)
+            .map("stability", stability_stage).sink("stability_out")
+            .map("alert", alert_stage).sink("alerts")
+            .compile())
 
     eps = make_endpoints(1)
     broker = Broker(GroupPlan(2, 1, 2), eps, BrokerConfig(compress="none"))
-    engine = StreamEngine([e.handle for e in eps], dag, n_executors=2,
+    engine = StreamEngine([e.handle for e in eps], plan, n_executors=2,
                           trigger_interval=0.05)
 
     # stream 0: strongly decaying (unstable score); stream 1: neutral rotation
@@ -65,8 +56,8 @@ def test_dag_in_engine_with_alerting():
     broker.flush()
     engine.drain_and_stop()
 
-    stab = {k: v for k, v, _ in dag.results("stability")}
+    stab = {k: v for k, v, _ in plan.results("stability_out")}
     assert len(stab) == 2
-    unstable_keys = {k for k, v, _ in dag.results("alert")}
+    unstable_keys = {k for k, v, _ in plan.results("alerts")}
     assert any("r0" in k for k in unstable_keys)     # decaying stream alerted
     assert not any("r1" in k for k in unstable_keys) # rotation is neutral

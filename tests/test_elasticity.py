@@ -24,6 +24,8 @@ from repro.streaming.endpoint import make_endpoints
 from repro.streaming.engine import StreamEngine
 from repro.workflow import Session, WorkflowConfig
 
+from one_stage import one_stage
+
 
 # ------------------------------------------------- race-free per-sender stats
 def test_broker_stats_exact_under_concurrent_writers():
@@ -100,7 +102,8 @@ def test_telemetry_snapshot_covers_all_layers():
     clk.attach()
     cfg = WorkflowConfig(n_producers=2, n_groups=1, executors_per_group=2,
                          compress="none", trigger_interval=0.05, min_batch=1)
-    with Session(cfg, analyze=_slow_analyzer(0.0), clock=clk) as sess:
+    with Session(cfg, pipeline=one_stage(_slow_analyzer(0.0)),
+                 clock=clk) as sess:
         h = sess.open_field("f", shape=(8,))
         bus = TelemetryBus(broker=sess.broker,
                            endpoints=[e.handle for e in sess.endpoints],
@@ -204,7 +207,7 @@ def _mk_loop(n_exec=1, cost=0.02, el=None, n_eps=1, clock=None):
                                             backpressure="block",
                                             queue_capacity=4096), clock=clk)
     eng = StreamEngine([e.handle for e in eps],
-                       _slow_analyzer(cost, clock=clk),
+                       one_stage(_slow_analyzer(cost, clock=clk)).compile(),
                        n_exec, trigger_interval=0.02, min_batch=1, clock=clk)
     bus = TelemetryBus(broker=broker, endpoints=[e.handle for e in eps],
                        engine=eng, clock=clk)
@@ -436,7 +439,7 @@ def test_slow_uniform_analysis_is_not_declared_dead():
                                             backpressure="block",
                                             queue_capacity=4096), clock=clk)
     eng = StreamEngine([e.handle for e in eps],
-                       _slow_analyzer(0.4, clock=clk),
+                       one_stage(_slow_analyzer(0.4, clock=clk)).compile(),
                        n_executors=2, trigger_interval=0.03, min_batch=1,
                        clock=clk)
     bus = TelemetryBus(broker=broker, endpoints=[e.handle for e in eps],
@@ -469,7 +472,7 @@ def test_session_owns_controller_lifecycle():
         n_producers=2, n_groups=1, executors_per_group=1, compress="none",
         trigger_interval=0.05, min_batch=1,
         elasticity=ElasticityConfig(enabled=True, interval_s=0.05))
-    sess = Session(cfg, analyze=_slow_analyzer(0.0))
+    sess = Session(cfg, pipeline=one_stage(_slow_analyzer(0.0)))
     assert sess.controller is not None and sess.controller.is_alive()
     assert sess.telemetry is not None and sess.detector is not None
     h = sess.open_field("f", shape=(4,))
@@ -488,7 +491,7 @@ def test_session_owns_controller_lifecycle():
 def test_session_without_elasticity_has_no_control_plane():
     cfg = WorkflowConfig(n_producers=1, n_groups=1, executors_per_group=1,
                          compress="none")
-    with Session(cfg, analyze=_slow_analyzer(0.0)) as sess:
+    with Session(cfg, pipeline=one_stage(_slow_analyzer(0.0))) as sess:
         assert sess.controller is None and sess.telemetry is None
 
 
@@ -513,7 +516,7 @@ def test_endpoint_failure_detected_and_recovered_no_drops():
             seen.setdefault(key, []).extend(r.step for r in records)
         return len(records)
 
-    sess = Session(cfg, analyze=analyze)
+    sess = Session(cfg, pipeline=one_stage(analyze))
     clk = sess.clock
     h = sess.open_field("f", shape=(8,))
     n_steps = 30

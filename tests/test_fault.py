@@ -25,6 +25,8 @@ from repro.runtime.telemetry import TelemetryBus
 from repro.streaming.endpoint import make_endpoints
 from repro.streaming.engine import StreamEngine
 
+from one_stage import one_stage
+
 
 def test_heartbeat_failure_detection():
     clk = VirtualClock()
@@ -86,8 +88,9 @@ def test_straggler_callback_drives_executor_replacement():
             seen.setdefault(key, []).extend(r.step for r in recs)
         return len(recs)
 
-    eng = StreamEngine([e.handle for e in eps], analyze, n_executors=3,
-                       trigger_interval=0.03, min_batch=1, clock=clk)
+    eng = StreamEngine([e.handle for e in eps], one_stage(analyze).compile(),
+                       n_executors=3, trigger_interval=0.03, min_batch=1,
+                       clock=clk)
     straggler = eng.executors[0]
     straggler.slowdown = 0.5               # ~10x its peers' service time
     bus = TelemetryBus(broker=broker, endpoints=[e.handle for e in eps],
@@ -130,7 +133,8 @@ def test_dead_executor_heartbeat_timeout_triggers_replacement():
     plan = GroupPlan(n_producers=1, n_groups=1, executors_per_group=2)
     broker = Broker(plan, eps, BrokerConfig(compress="none"), clock=clk)
     eng = StreamEngine([e.handle for e in eps],
-                       lambda k, recs: len(recs), n_executors=1,
+                       one_stage(lambda k, recs: len(recs)).compile(),
+                       n_executors=1,
                        trigger_interval=0.03, min_batch=1, clock=clk)
     bus = TelemetryBus(broker=broker, endpoints=[e.handle for e in eps],
                        engine=eng, clock=clk)

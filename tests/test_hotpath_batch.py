@@ -16,6 +16,8 @@ from repro.kernels import ops, ref
 from repro.streaming.endpoint import make_endpoints
 from repro.streaming.engine import StreamEngine
 
+from one_stage import one_stage
+
 
 # ------------------------------------------------------------ fused kernel
 @pytest.mark.parametrize("n,d", [(64, 64), (300, 200), (5, 96), (1, 32),
@@ -256,7 +258,8 @@ def test_engine_min_batch_holds_until_threshold():
     plan = GroupPlan(n_producers=1, n_groups=1, executors_per_group=1)
     broker = Broker(plan, eps, BrokerConfig(compress="none",
                                             max_batch_records=1))
-    eng = StreamEngine([e.handle for e in eps], lambda k, r: len(r), 1,
+    eng = StreamEngine([e.handle for e in eps],
+                       one_stage(lambda k, r: len(r)).compile(), 1,
                        trigger_interval=60.0, min_batch=4)
     try:
         for st in range(2):
@@ -282,7 +285,8 @@ def test_engine_min_batch_age_release():
     plan = GroupPlan(n_producers=1, n_groups=1, executors_per_group=1)
     broker = Broker(plan, eps, BrokerConfig(compress="none",
                                             max_batch_records=1))
-    eng = StreamEngine([e.handle for e in eps], lambda k, r: len(r), 1,
+    eng = StreamEngine([e.handle for e in eps],
+                       one_stage(lambda k, r: len(r)).compile(), 1,
                        trigger_interval=0.1, min_batch=100)
     try:
         for st in range(3):
@@ -303,7 +307,8 @@ def test_engine_drain_flushes_held_records():
     plan = GroupPlan(n_producers=1, n_groups=1, executors_per_group=1)
     broker = Broker(plan, eps, BrokerConfig(compress="none",
                                             max_batch_records=1))
-    eng = StreamEngine([e.handle for e in eps], lambda k, r: len(r), 1,
+    eng = StreamEngine([e.handle for e in eps],
+                       one_stage(lambda k, r: len(r)).compile(), 1,
                        trigger_interval=60.0, min_batch=100)
     broker.write("f", 0, 0, np.arange(4, dtype=np.float32))
     broker.flush()

@@ -1,7 +1,6 @@
 """Stream-operator API: typed operators, ordering contracts, event-time
 windows with keyed state, plan-aware engine dispatch (intra-stream
-parallelism), snapshot/restore migration, and the legacy Pipeline/
-AnalysisDAG compat shim compiling onto the same machinery."""
+parallelism), and snapshot/restore migration."""
 import threading
 
 import numpy as np
@@ -10,13 +9,14 @@ import pytest
 from repro.core.records import StreamRecord
 from repro.runtime.clock import VirtualClock
 from repro.sim.scenario import LoadPhase, Scenario, ScenarioRunner
-from repro.streaming.dag import AnalysisDAG, Stage
 from repro.streaming.operators import (KEYED, ORDERED, UNORDERED, Aggregate,
                                        BatchAggregate, Element, ExecutionPlan,
                                        Filter, KeyBy, Map, OperatorPipeline,
                                        Sink, SlidingWindow, TumblingWindow,
-                                       WindowPane, lower_dag)
-from repro.workflow import Pipeline, Session, WorkflowConfig
+                                       WindowPane)
+from repro.workflow import Session, WorkflowConfig
+
+from one_stage import one_stage
 
 
 def _rec(step, t, rank=0, val=None, dim=4):
@@ -25,25 +25,23 @@ def _rec(step, t, rank=0, val=None, dim=4):
 
 
 # ------------------------------------------------------------------ builder
-def test_builder_validation():
-    with pytest.raises(ValueError, match="duplicate operator"):
-        OperatorPipeline().map("a", None).map("a", None)
-    with pytest.raises(ValueError, match="unknown operator"):
-        OperatorPipeline().map("a", None).at("zz")
-    with pytest.raises(ValueError, match="unknown operator"):
-        OperatorPipeline().map("a", None).map("b", None, after="zz")
-    with pytest.raises(ValueError, match="no upstream"):
-        OperatorPipeline().map("a", None, after="x")
-    with pytest.raises(ValueError, match="empty pipeline"):
-        OperatorPipeline().compile()
-    with pytest.raises(ValueError, match="ordering must be one of"):
-        Map("m", None, ordering="chaotic")
-    with pytest.raises(ValueError, match="parallelism"):
-        Map("m", None, parallelism=0)
-    with pytest.raises(ValueError, match="size_s"):
-        TumblingWindow("w", 0.0)
-    with pytest.raises(ValueError, match="slide_s must be <= size_s"):
-        SlidingWindow("w", 1.0, 2.0)
+@pytest.mark.parametrize("build, match", [
+    (lambda: OperatorPipeline().map("a", None).map("a", None),
+     "duplicate operator"),
+    (lambda: OperatorPipeline().map("a", None).at("zz"), "unknown operator"),
+    (lambda: OperatorPipeline().map("a", None).map("b", None, after="zz"),
+     "unknown operator"),
+    (lambda: OperatorPipeline().map("a", None, after="x"), "no upstream"),
+    (lambda: OperatorPipeline().compile(), "empty pipeline"),
+    (lambda: Map("m", None, ordering="chaotic"), "ordering must be one of"),
+    (lambda: Map("m", None, parallelism=0), "parallelism"),
+    (lambda: TumblingWindow("w", 0.0), "size_s"),
+    (lambda: SlidingWindow("w", 1.0, 2.0), "slide_s must be <= size_s"),
+], ids=["duplicate", "at-unknown", "after-unknown", "source-after",
+        "empty", "ordering", "parallelism", "window-size", "slide"])
+def test_builder_validation(build, match):
+    with pytest.raises(ValueError, match=match):
+        build()
 
 
 def test_plan_phase_split_and_contract():
@@ -420,87 +418,24 @@ def test_window_state_survives_replace_executor_midwindow():
     assert total == 2 * n_steps
 
 
-# --------------------------------------------------------------- compat shim
-def _legacy_stages():
-    def source(key, records):
-        return sorted(r.step for r in records)
-
-    def double(key, steps):
-        return [s * 2 for s in steps]
-
-    def flag(key, steps):
-        return "big" if len(steps) >= 3 else None
-
-    return source, double, flag
-
-
-def test_legacy_pipeline_compiles_onto_operators_with_warning():
-    source, double, flag = _legacy_stages()
-    pipe = (Pipeline().stage("src", source).then("double", double)
-            .branch("flag", flag))
-    cfg = WorkflowConfig(n_producers=2, n_groups=1, executors_per_group=2,
-                         compress="none", trigger_interval=0.05)
-    with pytest.warns(DeprecationWarning, match="OperatorPipeline"):
-        sess = Session(cfg, pipeline=pipe)
-    assert sess.exec_plan is not None
-    assert sess.exec_plan.granularity == "batch"
-    assert sess.exec_plan.contract == ORDERED          # legacy = all ordered
-    assert not sess.exec_plan.parallel_dispatch        # sticky, ticketed
-    h = sess.open_field("f")
-    for s in range(4):
-        h.write_batch(s, [np.zeros(4, np.float32)] * 2, ranks=[0, 1])
-    sess.flush()
-    sess.close()
-    assert set(sess.dag.latest("double")) == {"f/g0/r0", "f/g0/r1"}
-    # engine Result.value is still the source stage's output (legacy shape)
-    for r in sess.results():
-        assert isinstance(r.value, list)
-
-
-def test_legacy_dag_identical_results_through_new_compiler():
-    """The old API's results must come out of the operator compiler
-    byte-identical to direct AnalysisDAG execution on the same batches."""
-    source, double, flag = _legacy_stages()
-
-    def fresh_dag():
-        return AnalysisDAG(
-            [Stage("src", source, ["double"]),
-             Stage("double", double, ["flag"]),
-             Stage("flag", flag, [])],
-            source="src")
-
-    batches = [(f"f/g0/r{r}", [_rec(s + 4 * b, t=0.01 * (s + 4 * b), rank=r)
-                               for s in range(3 + (b % 2))])
-               for r in range(2) for b in range(4)]
-
-    direct = fresh_dag()
-    direct_returns = [direct(key, recs) for key, recs in batches]
-
-    lowered_dag = fresh_dag()
-    plan = lower_dag(lowered_dag)
-    plan_returns = [plan(key, recs) for key, recs in batches]
-
-    assert plan_returns == direct_returns
-    for stage in ("src", "double", "flag"):
-        assert [(k, v) for k, v, _t in lowered_dag.results(stage)] \
-            == [(k, v) for k, v, _t in direct.results(stage)], stage
-
-
-def test_attach_analyzer_detaches_operator_plan():
+# ------------------------------------------------------ replacing the plan
+def test_attach_pipeline_replaces_operator_plan():
     pipe = (OperatorPipeline()
             .map("m", lambda k, rec: rec.step, ordering=UNORDERED)
             .sink("out"))
     cfg = WorkflowConfig(n_producers=1, n_groups=1, executors_per_group=1,
                          compress="none", trigger_interval=0.05)
     sess = Session(cfg, pipeline=pipe)
-    assert sess.engine.plan is not None
-    sess.attach_analyzer(lambda k, recs: "swapped")
-    assert sess.engine.plan is None
+    first = sess.engine.plan
+    assert first is sess.exec_plan
+    swapped = sess.attach_pipeline(one_stage(lambda k, recs: "swapped"))
+    assert sess.engine.plan is swapped is sess.exec_plan
     h = sess.open_field("f")
     h.write(0, np.zeros(4, np.float32))
     sess.flush()
     sess.close()
     assert [r.value for r in sess.results()] == ["swapped"]
+    assert first.results("out") == []         # the replaced plan saw nothing
 
 
 # ------------------------------------------------------ scenario integration
@@ -581,7 +516,8 @@ def test_attach_plan_midrun_seeds_frontier():
                          clock="virtual")
     clock = VirtualClock(seed=0)
     clock.attach()
-    sess = Session(cfg, analyze=lambda k, recs: len(recs), clock=clock)
+    sess = Session(cfg, pipeline=one_stage(lambda k, recs: len(recs)),
+                   clock=clock)
     h = sess.open_field("f", shape=(4,))
     for s in range(10):                       # burn seqs on the callback path
         h.write(s, np.zeros(4, np.float32))

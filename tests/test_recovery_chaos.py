@@ -14,6 +14,8 @@ from repro.streaming.operators import OperatorPipeline
 from repro.workflow import ElasticityConfig, WorkflowConfig
 from repro.workflow.session import Session
 
+from one_stage import one_stage
+
 SEEDS = [0, 1, 2]
 
 
@@ -100,7 +102,7 @@ def test_retry_exhaustion_warns_and_counts_frames_abandoned():
     exhaustion now raises a RuntimeWarning and bumps frames_abandoned."""
     cfg = _wf(delivery="at-most-once", flush_timeout_s=0.5, retry_limit=2)
     with pytest.warns(RuntimeWarning, match="abandon"):
-        with Session(cfg, analyze=lambda k, r: None) as sess:
+        with Session(cfg, pipeline=one_stage(lambda k, r: None)) as sess:
             for ep in sess.endpoints:
                 ep.handle.fail()
             h = sess.open_field("f", shape=(4,))
@@ -118,7 +120,8 @@ def test_virtual_clock_loopback_transport_validates_and_delivers():
     cfg = _wf(transport="loopback")
     cfg.validate()                            # formerly raised ValueError
     seen = []
-    with Session(cfg, analyze=lambda k, r: seen.append(len(r))) as sess:
+    with Session(cfg,
+                 pipeline=one_stage(lambda k, r: seen.append(len(r)))) as sess:
         h = sess.open_field("f", shape=(4,))
         for s in range(12):
             h.write_batch(s, [np.full(4, s, dtype=np.float32)] * 4,

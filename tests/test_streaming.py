@@ -3,11 +3,16 @@ elastic scaling — the Spark-side semantics the paper leans on."""
 import time
 
 import numpy as np
+import pytest
 
 from repro.core.broker import Broker, BrokerConfig
 from repro.core.grouping import GroupPlan
+from repro.core.records import StreamRecord
+from repro.runtime.clock import VirtualClock
 from repro.streaming.endpoint import make_endpoints
-from repro.streaming.engine import StreamEngine
+from repro.streaming.engine import MicroBatch, StreamEngine
+
+from one_stage import one_stage
 
 
 def _push(broker, n_ranks=4, steps=5):
@@ -21,8 +26,8 @@ def _mk_engine(n_eps=1, n_exec=2, analyze=None, trigger=0.05, n_ranks=4):
     plan = GroupPlan(n_producers=n_ranks, n_groups=n_eps, executors_per_group=2)
     broker = Broker(plan, eps, BrokerConfig(compress="none"))
     analyze = analyze or (lambda key, recs: len(recs))
-    eng = StreamEngine([e.handle for e in eps], analyze, n_exec,
-                       trigger_interval=trigger)
+    eng = StreamEngine([e.handle for e in eps], one_stage(analyze).compile(),
+                       n_exec, trigger_interval=trigger)
     return broker, eps, eng
 
 
@@ -137,9 +142,6 @@ def test_per_stream_order_survives_stealing():
     executor + single hot stream forces steals; per-stream sequence tickets
     must keep analysis order == dispatch order."""
     import threading
-    broker, eps, eng = _mk_engine(n_exec=2, trigger=30, n_ranks=1)
-    eng.min_batch = 1
-    eng.executors[0].slowdown = 0.05
     order: dict[str, list[int]] = {}
     in_flight: dict[str, int] = {}
     overlap = []
@@ -156,7 +158,10 @@ def test_per_stream_order_survives_stealing():
             order.setdefault(key, []).extend(r.step for r in recs)
         return len(recs)
 
-    eng.analyze_fn = analyze
+    broker, eps, eng = _mk_engine(n_exec=2, analyze=analyze, trigger=30,
+                                  n_ranks=1)
+    eng.min_batch = 1
+    eng.executors[0].slowdown = 0.05
     for step in range(30):                 # many 1-record batches, one stream
         broker.write("f", 0, step, np.full(8, float(step), np.float32))
         broker.flush()
@@ -211,3 +216,65 @@ def test_elastic_scale_up_down():
     eng.drain_and_stop()
     assert sum(r.n_records for r in eng.collect()) == 24
     assert len([e for e in eng.executors if e.alive]) == 0  # stopped
+
+
+def _returns(key, recs):
+    return ("seen", key, len(recs))
+
+
+def _returns_none(key, recs):
+    return None
+
+
+def _raises(key, recs):
+    raise RuntimeError(f"bad batch on {key}")
+
+
+@pytest.mark.parametrize("fn", [_returns, _returns_none, _raises],
+                         ids=["value", "none", "raises"])
+def test_one_stage_plan_result_value(fn):
+    """A callback run as the one-stage plan gives each Result.value as the
+    callback itself would: its return value, None, or the exception."""
+    broker, eps, eng = _mk_engine(n_exec=2, analyze=fn)
+    _push(broker, steps=3)
+    broker.flush()
+    eng.drain_and_stop()
+    results = eng.collect()
+    assert sum(r.n_records for r in results) == 12
+    for r in results:
+        try:
+            want = fn(r.stream_key, [None] * r.n_records)
+        except RuntimeError as e:
+            assert type(r.value) is RuntimeError and r.value.args == e.args
+        else:
+            assert r.value == want
+
+
+def test_slowdown_on_ordered_plan_sleeps_after_the_ticket():
+    """On a plan with no order-insensitive prefix, a straggler sleeps once
+    it holds its ordering turn, not while it waits for it: with every
+    executor slowed by S, the second batch of a stream is analyzed S after
+    the first (virtual time), not alongside it."""
+    slow = 1.0
+    clk = VirtualClock()
+    clk.attach()
+    analyzed: list[tuple[int, float]] = []
+
+    def analyze(key, recs):
+        analyzed.append((recs[0].step, clk.now()))
+        return len(recs)
+
+    eng = StreamEngine([], one_stage(analyze).compile(), 2,
+                       trigger_interval=60.0, clock=clk)
+    for ex in eng.executors:
+        ex.slowdown = slow
+    for seq, ex in enumerate(eng.executors):   # one stream, two executors
+        rec = StreamRecord("f", 0, 0, seq, np.zeros(4, np.float32))
+        ex.q.put(MicroBatch(stream_key="f/g0/r0", records=[rec], seq=seq))
+    assert clk.wait(lambda: len(eng.collect()) == 2, timeout=10.0)
+    eng.drain_and_stop()
+    clk.detach()
+    (s0, t0), (s1, t1) = analyzed
+    assert (s0, s1) == (0, 1)
+    assert t0 >= slow
+    assert t1 - t0 >= slow, "the ticket wait overlapped the slowdown"

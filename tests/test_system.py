@@ -24,6 +24,8 @@ from repro.sim.cfd import CFDConfig, init_state, region_fields, step
 from repro.streaming.endpoint import make_endpoints
 from repro.streaming.engine import StreamEngine
 
+from one_stage import one_stage
+
 
 def _dmd_analyzer(n_features):
     states = {}
@@ -45,7 +47,8 @@ def test_cfd_insitu_workflow():
     broker = broker_connect(eps, n_producers=cfg.n_regions,
                             cfg=BrokerConfig(compress="int8+zstd"),
                             plan=GroupPlan(cfg.n_regions, 2, 2))
-    engine = StreamEngine([e.handle for e in eps], _dmd_analyzer(n_feat),
+    engine = StreamEngine([e.handle for e in eps],
+                          one_stage(_dmd_analyzer(n_feat)).compile(),
                           n_executors=4, trigger_interval=0.05)
     ctxs = [broker_init("velocity", r) for r in range(cfg.n_regions)]
 
@@ -87,7 +90,8 @@ def test_training_tap_workflow():
                             plan=GroupPlan(n_regions, 2, 2))
     streamer = TapStreamer(broker, n_regions=n_regions)
     engine = StreamEngine([e.handle for e in eps],
-                          _dmd_analyzer(cfg.tap_snapshot_dim),
+                          one_stage(_dmd_analyzer(cfg.tap_snapshot_dim))
+                          .compile(),
                           n_executors=2, trigger_interval=0.05)
 
     losses = []

@@ -284,12 +284,13 @@ def test_session_wal_dir_persists_log_across_sessions(tmp_path):
     Session over the same directory adopts the surviving segments."""
     import numpy as np
 
+    from one_stage import one_stage
     from repro.workflow import Session, WorkflowConfig
     cfg = WorkflowConfig(n_producers=2, n_groups=1, executors_per_group=1,
                          compress="none", backpressure="block",
                          trigger_interval=0.05, delivery="exactly-once",
                          wal_dir=str(tmp_path / "wal"))
-    with Session(cfg, analyze=lambda k, recs: len(recs)) as sess:
+    with Session(cfg, pipeline=one_stage(lambda k, recs: len(recs))) as sess:
         h = sess.open_field("f", shape=(4,))
         for s in range(5):
             for r in range(2):

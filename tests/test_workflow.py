@@ -1,5 +1,6 @@
-"""repro.workflow: WorkflowConfig round-trip, Session lifecycle, Pipeline
-builder, FieldHandle batching, the compat shim, and the broker regressions
+"""repro.workflow: WorkflowConfig round-trip, Session lifecycle, the
+OperatorPipeline builder, FieldHandle batching, the compat shim, and the
+broker regressions
 fixed alongside the redesign (flush early-return, silent plan shrink,
 failover with batched frames in flight)."""
 import itertools
@@ -15,9 +16,12 @@ from repro.core.api import (broker_connect, broker_finalize, broker_init,
 from repro.core.broker import Broker, BrokerConfig
 from repro.core.grouping import GroupPlan
 from repro.core.taps import TapStreamer
-from repro.streaming.dag import AnalysisDAG, Stage
 from repro.streaming.endpoint import make_endpoints
-from repro.workflow import FieldHandle, Pipeline, Session, WorkflowConfig
+from repro.streaming.operators import Map
+from repro.workflow import (ExecutionPlan, FieldHandle, OperatorPipeline,
+                            Session, WorkflowConfig)
+
+from one_stage import one_stage
 
 
 # ------------------------------------------------------------- WorkflowConfig
@@ -77,7 +81,7 @@ def _count_analyzer():
 def test_session_end_to_end_context_manager():
     cfg = WorkflowConfig(n_producers=4, n_groups=2, executors_per_group=2,
                          compress="none", trigger_interval=0.05)
-    with Session(cfg, analyze=_count_analyzer()) as sess:
+    with Session(cfg, pipeline=one_stage(_count_analyzer())) as sess:
         h = sess.open_field("f", shape=(8,))
         assert sess.open_field("f", shape=(8,)) is h      # cached handle
         for s in range(6):
@@ -106,13 +110,14 @@ def test_session_field_handle_typing():
     assert sess.stats.sent == 2
 
 
-def test_session_attach_analyzer_swaps_engine_fn():
+def test_session_attach_pipeline_swaps_engine_plan():
     cfg = WorkflowConfig(n_producers=1, n_groups=1, executors_per_group=1,
                          compress="none", trigger_interval=0.05)
-    sess = Session(cfg, analyze=_count_analyzer())
+    sess = Session(cfg, pipeline=one_stage(_count_analyzer()))
     engine = sess.engine
-    sess.attach_analyzer(lambda k, recs: "swapped")
-    assert sess.engine is engine                        # same engine, new fn
+    plan = sess.attach_pipeline(one_stage(lambda k, recs: "swapped"))
+    assert sess.engine is engine                      # same engine, new plan
+    assert engine.plan is plan is sess.exec_plan
     h = sess.open_field("f")
     h.write(0, np.zeros(4, np.float32))
     sess.flush()
@@ -129,7 +134,7 @@ def test_session_init_failure_does_not_leak_threads():
         Session(WorkflowConfig(n_producers=2, n_groups=1,
                                executors_per_group=1, compress="none",
                                transport="loopback"),
-                pipeline=Pipeline())
+                pipeline=OperatorPipeline())
     deadline = time.time() + 2.0
     while time.time() < deadline:
         leaked = [t for t in threading.enumerate() if t not in before
@@ -141,112 +146,92 @@ def test_session_init_failure_does_not_leak_threads():
     assert not leaked, f"leaked threads: {[t.name for t in leaked]}"
 
 
-# ----------------------------------------------------------------- Pipeline
+# ------------------------------------------------------------ OperatorPipeline
 def test_pipeline_builder_topology():
-    pipe = (Pipeline()
-            .stage("dmd", lambda k, recs: len(recs))
-            .then("stability", lambda k, v: v * 2)
-            .branch("trend", lambda k, v: -v)
-            .at("stability").then("alert", lambda k, v: v if v > 2 else None))
+    pipe = (OperatorPipeline(granularity="batch")
+            .map("dmd", lambda k, recs: len(recs))
+            .map("stability", lambda k, v: v * 2)
+            .map("trend", lambda k, v: -v, after="dmd")
+            .at("stability").map("alert", lambda k, v: v if v > 2 else None))
     assert set(pipe.edges()) == {("dmd", "stability"), ("dmd", "trend"),
                                  ("stability", "alert")}
-    dag = pipe.compile()
-    assert isinstance(dag, AnalysisDAG)
-    assert dag.source == "dmd"
-    assert sorted(dag.stages["dmd"].downstream) == ["stability", "trend"]
-
-
-def test_pipeline_builder_rejects_misuse():
-    with pytest.raises(ValueError, match="already declared"):
-        Pipeline().stage("a", None).stage("b", None)
-    with pytest.raises(ValueError, match="duplicate stage"):
-        Pipeline().stage("a", None).then("a", None)
-    with pytest.raises(ValueError, match="before then"):
-        Pipeline().then("a", None)
-    with pytest.raises(ValueError, match="no parent"):
-        Pipeline().stage("a", None).branch("b", None)
-    with pytest.raises(ValueError, match="empty pipeline"):
-        Pipeline().compile()
-    with pytest.raises(ValueError, match="unknown stage"):
-        Pipeline().stage("a", None).at("zz")
-    with pytest.raises(ValueError, match="duplicate stage names"):
-        AnalysisDAG([Stage("a", None), Stage("a", None)], source="a")
+    plan = pipe.compile()
+    assert isinstance(plan, ExecutionPlan)
+    assert plan.source == "dmd"
+    assert sorted(plan.down["dmd"]) == ["stability", "trend"]
 
 
 def test_pipeline_branch_after_at_fans_out_from_new_cursor():
-    """branch() after at() must fan out from the repositioned cursor's
-    parent, not the construction-order tail."""
-    pipe = (Pipeline()
-            .stage("src", lambda k, v: v)
-            .then("a", lambda k, v: v)
-            .then("deep", lambda k, v: v)
-            .at("a").branch("b", lambda k, v: v))     # sibling of a (src->b)
+    """A fan-out after at() hangs off the operator named in after=, not
+    the construction-order tail."""
+    pipe = (OperatorPipeline()
+            .map("src", lambda k, v: v)
+            .map("a", lambda k, v: v)
+            .map("deep", lambda k, v: v)
+            .at("a").map("b", lambda k, v: v, after="src"))  # sibling of a
     assert set(pipe.edges()) == {("src", "a"), ("a", "deep"), ("src", "b")}
-    # at() the source: branch still has no parent to fan out from
-    with pytest.raises(ValueError, match="no parent"):
-        pipe.at("src").branch("c", lambda k, v: v)
 
 
 def test_pipeline_at_cannot_introduce_cycle():
     """The builder only ever attaches NEW nodes below existing ones, so a
-    back-edge is unreachable: re-adding an ancestor via then() after at()
-    hits the duplicate check, and the compiled DAG always validates
-    acyclic."""
-    pipe = (Pipeline()
-            .stage("a", lambda k, v: v)
-            .then("b", lambda k, v: v))
-    with pytest.raises(ValueError, match="duplicate stage"):
-        pipe.at("b").then("a", lambda k, v: v)        # would be the back-edge
-    pipe.at("b").then("c", lambda k, v: v)
+    back-edge is unreachable: re-adding an ancestor after at() hits the
+    duplicate check, and the compiled plan always validates acyclic."""
+    pipe = (OperatorPipeline()
+            .map("a", lambda k, v: v)
+            .map("b", lambda k, v: v))
+    with pytest.raises(ValueError, match="duplicate operator"):
+        pipe.at("b").map("a", lambda k, v: v)         # would be the back-edge
+    pipe.at("b").map("c", lambda k, v: v)
     pipe.compile()                                    # still acyclic
 
-    # a hand-assembled cyclic Stage list is rejected by AnalysisDAG itself
+    # a hand-assembled cyclic graph is rejected by ExecutionPlan itself
     with pytest.raises(ValueError, match="cycle"):
-        AnalysisDAG([Stage("a", None, ["b"]), Stage("b", None, ["a"])],
-                    source="a")
+        ExecutionPlan({"a": Map("a", None), "b": Map("b", None)},
+                      {"a": ["b"], "b": ["a"]}, "a")
 
 
 def test_pipeline_duplicate_names_rejected_across_all_verbs():
-    with pytest.raises(ValueError, match="duplicate stage"):
-        Pipeline().stage("x", None).then("y", None).branch("y", None)
-    with pytest.raises(ValueError, match="duplicate stage"):
-        Pipeline().stage("x", None).then("y", None).at("x").then("y", None)
+    with pytest.raises(ValueError, match="duplicate operator"):
+        (OperatorPipeline().map("x", None).map("y", None)
+         .map("y", None, after="x"))
+    with pytest.raises(ValueError, match="duplicate operator"):
+        OperatorPipeline().map("x", None).map("y", None).at("x").sink("y")
     with pytest.raises(ValueError, match="non-empty"):
-        Pipeline().stage("", None)
+        OperatorPipeline().map("", None)
 
 
 def test_pipeline_runs_in_session():
     cfg = WorkflowConfig(n_producers=2, n_groups=1, executors_per_group=2,
                          compress="none", trigger_interval=0.05)
-    pipe = (Pipeline()
-            .stage("count", lambda k, recs: len(recs))
-            .then("double", lambda k, v: v * 2)
-            .branch("flag", lambda k, v: "big" if v >= 3 else None))
+    pipe = (OperatorPipeline(granularity="batch")
+            .map("count", lambda k, recs: len(recs))
+            .map("double", lambda k, v: v * 2).sink("doubles")
+            .map("flag", lambda k, v: "big" if v >= 3 else None,
+                 after="count").sink("flags"))
     with Session(cfg, pipeline=pipe) as sess:
         h = sess.open_field("f")
         for s in range(3):
             h.write_batch(s, [np.zeros(4, np.float32)] * 2, ranks=[0, 1])
         sess.flush()
-    doubles = sess.dag.latest("double")
+    doubles = sess.exec_plan.latest("doubles")
     assert set(doubles) == {"f/g0/r0", "f/g0/r1"}
     assert all(v % 2 == 0 for v in doubles.values())
-    assert sess.results("double") == sess.dag.results("double")
+    assert sess.results("doubles") == sess.exec_plan.results("doubles")
     # "flag" filtered: only micro-batches of >= 3 records sink
-    assert all(v == "big" for _, v, _ in sess.results("flag"))
+    assert all(v == "big" for _, v, _ in sess.results("flags"))
 
 
-def test_engine_attach_dag_reroutes_microbatches():
+def test_engine_attach_plan_reroutes_microbatches():
     cfg = WorkflowConfig(n_producers=1, n_groups=1, executors_per_group=1,
                          compress="none", trigger_interval=0.05)
-    sess = Session(cfg, analyze=_count_analyzer())
-    dag = (Pipeline().stage("only", lambda k, recs: f"dag:{len(recs)}")
-           .compile())
-    sess.engine.attach_dag(dag)
+    sess = Session(cfg, pipeline=one_stage(_count_analyzer()))
+    plan = one_stage(lambda k, recs: f"plan:{len(recs)}").compile()
+    sess.engine.attach_plan(plan)
     h = sess.open_field("f")
     h.write(0, np.zeros(2, np.float32))
     sess.flush()
     sess.close()
-    assert [r.value for r in sess.results()] == ["dag:1"]
+    assert [r.value for r in sess.results()] == ["plan:1"]
 
 
 # -------------------------------------------------- FieldHandle.write_batch
@@ -428,7 +413,7 @@ def test_failover_midstream_batched_no_loss_ordered():
         seen.setdefault(key, []).extend(r.step for r in records)
         return len(records)
 
-    sess = Session(cfg, analyze=analyze)
+    sess = Session(cfg, pipeline=one_stage(analyze))
     h = sess.open_field("f")
     n_steps = 40
     for s in range(n_steps):
@@ -476,7 +461,7 @@ def test_loopback_transport_survives_broker_suite_smoke():
     cfg = WorkflowConfig(n_producers=4, n_groups=2, executors_per_group=2,
                          compress="int8+zstd", transport="loopback",
                          trigger_interval=0.05)
-    with Session(cfg, analyze=_count_analyzer()) as sess:
+    with Session(cfg, pipeline=one_stage(_count_analyzer())) as sess:
         h = sess.open_field("f", shape=(32,))
         for s in range(5):
             h.write_batch(s, [np.random.randn(32).astype(np.float32)] * 4,
