@@ -11,7 +11,9 @@ collector gathers results (rdd.collect) with generation->analysis latency.
 
 Beyond the paper (Spark gave these for free; we implement them):
   * work stealing   — idle executors steal queued partitions (straggler
-                      mitigation).  Steals migrate the stream's sticky
+                      mitigation).  An idle executor blocks on its own
+                      queue; a put that leaves a backlog wakes one of them
+                      to steal.  Steals migrate the stream's sticky
                       assignment to the thief, and per-stream sequence
                       tickets guarantee a stolen micro-batch is never
                       analyzed concurrently with — or ahead of — an earlier
@@ -48,6 +50,10 @@ from repro.runtime.telemetry import span
 # the pipeline if its stream's ticket chain broke (a dropped partition with
 # no surviving executor); counted in metrics()["order_timeouts"].
 _ORDER_WAIT_S = 5.0
+
+# An idle executor blocks on its own queue until a put or a wake token
+# lands there; this timeout is only the safety net against a lost wake.
+_IDLE_WAIT_S = 1.0
 
 # metrics() latency percentiles cover at most this much trailing wall time,
 # so a past breach episode ages out of the QoS signal instead of pinning
@@ -111,6 +117,7 @@ class _Executor(threading.Thread):
         self.alive = True
         self.processed = 0
         self.stolen = 0
+        self.idle_wakeups = 0          # wakeups that found nothing to do
         self.slowdown = 0.0            # straggler injection (tests/benches)
         self.current_key: str | None = None    # stream being analyzed now
         self.t_busy_since = 0.0        # when the current analysis started
@@ -120,12 +127,9 @@ class _Executor(threading.Thread):
         eng = self.engine
         clock = eng.clock
         while self.alive:
-            mb = clock.queue_get(self.q, timeout=0.02)
+            mb = self._take(eng, clock)
             if mb is None:
-                mb = eng._steal(self.idx)
-                if mb is None:
-                    continue
-                self.stolen += 1
+                continue
             if mb is _POISON:
                 break
             self.current_key = mb.stream_key
@@ -142,6 +146,38 @@ class _Executor(threading.Thread):
         # it was replaced and put the stolen run into its own dead queue)
         eng._reassign(self)
         clock.detach()     # exit the schedule without a watchdog stall
+
+    def _take(self, eng: "StreamEngine", clock):
+        """The next micro-batch: this executor's own queue first.  Else it
+        joins the engine's idle set, tries one steal and, finding nothing,
+        blocks on its queue until a dispatch, a shutdown or a wake token
+        lands there (``StreamEngine._wake_thief``: a backlog formed
+        elsewhere), then steals once.  None when a wakeup found nothing
+        to do."""
+        try:
+            mb = self.q.get_nowait()
+        except queue.Empty:
+            mb = None
+        if mb is not None and mb is not _WAKE:
+            return mb
+        # idle before the scan: a backlog that forms after it wakes us
+        eng._idle_enter(self)
+        try:
+            mb = eng._steal(self.idx)
+            if mb is None:
+                mb = clock.queue_get(self.q, timeout=_IDLE_WAIT_S)
+                if mb is not None and mb is not _WAKE:
+                    return mb              # own work, or _POISON
+                if not self.alive:
+                    return None
+                mb = eng._steal(self.idx)
+                if mb is None:
+                    self.idle_wakeups += 1
+                    return None
+        finally:
+            eng._idle_leave(self)
+        self.stolen += 1
+        return mb
 
     def _process(self, mb: MicroBatch, clock) -> None:
         """Analyse one micro-batch and hand its Result to the engine."""
@@ -208,6 +244,8 @@ class _Executor(threading.Thread):
 
 
 _POISON = MicroBatch(stream_key="__poison__", records=[])
+# put in an idle executor's queue to make it run one steal; carries no work
+_WAKE = MicroBatch(stream_key="__wake__", records=[])
 
 
 class StreamEngine:
@@ -262,6 +300,10 @@ class StreamEngine:
         self._done_seq: dict[str, int] = {}    # stream -> completed prefix
         self.order_timeouts = 0                # broken-chain escapes (rare)
         self.rebalances = 0
+        # executors blocked on an empty queue, longest idle first
+        self._ilock = threading.Lock()
+        self._idle: dict[int, _Executor] = {}
+        self.steal_wakes = 0                   # idle executors woken to steal
         self.attach_plan(plan)
         # executor-seconds integral (elasticity cost accounting)
         self._exec_secs = 0.0
@@ -376,8 +418,8 @@ class StreamEngine:
                 if ex.alive:
                     self._account_locked()
                     ex.alive = False
-                    ex.q.put(_POISON)
                     self._reassign(ex)
+                    ex.q.put(_POISON)      # after: _reassign drops poison
                     removed = ex.idx
                     break
         if removed is not None:
@@ -396,6 +438,7 @@ class StreamEngine:
             self._account_locked()
             ex.kill()
             self._reassign(ex)
+            ex.q.put(_POISON)          # a blocked executor exits now
         self.rebalance()
 
     def replace_executor(self, idx: int):
@@ -404,10 +447,10 @@ class StreamEngine:
         ex = self.executors[idx]
         with self._elock:
             self._account_locked()
-            if ex.alive:
-                ex.alive = False
-                ex.q.put(_POISON)
+            was_alive, ex.alive = ex.alive, False
             self._reassign(ex)
+            if was_alive:
+                ex.q.put(_POISON)      # after: _reassign drops poison
             new = self._add_executor_locked()
         self.rebalance()
         return new
@@ -431,13 +474,13 @@ class StreamEngine:
         self.rebalances += 1
         return n
 
-    @staticmethod
-    def _enqueue_in_seq_order(tgt: _Executor, mb: MicroBatch) -> None:
+    def _enqueue_in_seq_order(self, tgt: _Executor, mb: MicroBatch) -> None:
         """Insert a reassigned partition BEFORE any later-seq partition of
         the same stream already queued on the target (the driver may have
         dispatched newer batches to the new sticky executor while the dead
         one's queue was still being drained); plain append would make the
         target block on its own queue and then analyze out of order."""
+        self._idle_leave(tgt)
         with tgt.q.mutex:
             dq = tgt.q.queue
             pos = next((i for i, x in enumerate(dq)
@@ -449,6 +492,7 @@ class StreamEngine:
             else:
                 dq.insert(pos, mb)
             tgt.q.not_empty.notify()
+        self._wake_thief(tgt)
 
     def _reassign(self, dead: _Executor):
         moved = 0
@@ -457,7 +501,7 @@ class StreamEngine:
                 mb = dead.q.get_nowait()
             except queue.Empty:
                 break
-            if mb is _POISON:
+            if mb is _POISON or mb is _WAKE:
                 continue
             tgt = self._pick_executor(mb.stream_key, exclude=dead.idx)
             if tgt is not None:
@@ -509,6 +553,39 @@ class StreamEngine:
             alive = sorted(alive, key=lambda e: e.q.qsize())[:hint]
         return min(alive, key=lambda e: e.q.qsize())
 
+    # ---- idle executors and wakes -----------------------------------------
+    def _idle_enter(self, ex: _Executor) -> None:
+        with self._ilock:
+            self._idle[ex.idx] = ex
+
+    def _idle_leave(self, ex: _Executor) -> None:
+        with self._ilock:
+            self._idle.pop(ex.idx, None)
+
+    def _put(self, ex: _Executor, mb: MicroBatch) -> int:
+        """Queue ``mb`` on ``ex`` (the put itself wakes it, so it leaves the
+        idle set) and wake a thief if that left a backlog; returns the
+        number of executors woken to steal (0 or 1)."""
+        self._idle_leave(ex)
+        ex.q.put(mb)
+        return self._wake_thief(ex)
+
+    def _wake_thief(self, busy: _Executor) -> int:
+        """A put left ``busy``'s queue holding more than one item: wake the
+        longest-idle executor with a token so it runs one steal.  Returns
+        the number woken (0 or 1)."""
+        if busy.q.qsize() < 2:
+            return 0
+        with self._ilock:
+            thief = next((e for e in self._idle.values()
+                          if e is not busy and e.alive), None)
+            if thief is None:
+                return 0
+            del self._idle[thief.idx]
+            self.steal_wakes += 1
+        thief.q.put(_WAKE)
+        return 1
+
     # ---- work stealing ---------------------------------------------------
     @staticmethod
     def _peek_key(ex: _Executor) -> str | None:
@@ -541,6 +618,8 @@ class StreamEngine:
             if mb is _POISON:          # dying executor: hand it back
                 victim.q.put(_POISON)
                 continue
+            if mb is _WAKE:            # the victim has other items queued
+                continue
             if self.plan.parallel_dispatch and not self.plan.shuffled:
                 # parallel-dispatch plans have no sticky run to migrate:
                 # batches of one stream are already spread, so steal just
@@ -561,6 +640,7 @@ class StreamEngine:
             thief = self.executors[thief_idx]
             for x in rest:
                 thief.q.put(x)
+            self._wake_thief(thief)
             return mb
         return None
 
@@ -587,7 +667,7 @@ class StreamEngine:
         partitions dispatch with sticky partition->executor ownership (the
         partition, not the producer stream, is the unit the fleet owns),
         and seq tickets are issued per partition."""
-        n = 0
+        n = woken = 0
         now = self.clock.now()
         plan = self.plan
         shuffled = plan.shuffled
@@ -618,11 +698,11 @@ class StreamEngine:
                     continue
                 seq = self._next_seq.get(key, 0)
                 self._next_seq[key] = seq + 1
-                ex.q.put(MicroBatch(stream_key=key, records=held, seq=seq,
-                                    t_created=now))
+                woken += self._put(ex, MicroBatch(
+                    stream_key=key, records=held, seq=seq, t_created=now))
                 del self._hold[key], self._hold_t[key]
                 n += 1
-            sp.set_metadata(dispatched=n, held=len(self._hold))
+            sp.set_metadata(dispatched=n, held=len(self._hold), woken=woken)
         return n
 
     def held(self) -> int:
@@ -661,7 +741,9 @@ class StreamEngine:
     def metrics(self) -> dict:
         """Thread-safe control-plane snapshot: per-executor queue depth /
         steal counts, hold-buffer backlog, rolling (windowed) latency
-        percentiles, and the executor-seconds integral.  This is the
+        percentiles, the executor-seconds integral, and how often idle
+        executors woke: ``idle_wakeups`` (found nothing to do) and
+        ``steal_wakes`` (woken because a backlog formed).  This is the
         engine's feed into ``runtime.telemetry.TelemetryBus``."""
         def _qrecords(ex: _Executor) -> int:
             with ex.q.mutex:
@@ -710,7 +792,9 @@ class StreamEngine:
                 "latency_p99": percentile_sorted(lats, 0.99),
                 "executor_seconds": exec_secs,
                 "order_timeouts": self.order_timeouts,
-                "rebalances": self.rebalances}
+                "rebalances": self.rebalances,
+                "idle_wakeups": sum(e.idle_wakeups for e in self.executors),
+                "steal_wakes": self.steal_wakes}
 
     def drain_and_stop(self, timeout: float = 30.0):
         deadline = self.clock.now() + timeout

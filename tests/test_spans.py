@@ -59,6 +59,7 @@ def _run_session() -> dict:
     session.flush(timeout=60.0)
     stats = session.close()
     return {"sent": stats.sent, "dropped": stats.dropped,
+            "steal_wakes": session.engine.metrics()["steal_wakes"],
             "items": session.exec_plan.batch_stats()["dmd"]["items"],
             "eigs": {(k, step): e
                      for k, (step, e), _t in session.results("eigs")}}
@@ -140,6 +141,9 @@ def test_span_counts_match_the_program_counters(traced):
     assert total("analysis.solve", "panes") == out["items"]
     assert total("analysis.solve", "snapshots") == out["sent"]
     assert total("operators.insert", "late") == 0
+    # the plan spreads each stream's batches (no run to migrate) and no
+    # executor is retired, so only dispatches wake executors to steal
+    assert total("engine.trigger", "woken") == out["steal_wakes"]
     # one bucket per solve (every pane holds PANE snapshots): its slab is
     # (k_pad, m_pad, d) float32, d as the configuration gives it
     solves = [sp for sp in spans if sp["name"] == "analysis.solve"]

@@ -278,3 +278,173 @@ def test_slowdown_on_ordered_plan_sleeps_after_the_ticket():
     assert (s0, s1) == (0, 1)
     assert t0 >= slow
     assert t1 - t0 >= slow, "the ticket wait overlapped the slowdown"
+
+
+class _Feed:
+    """An endpoint handle filled by hand: the engine's drain API only."""
+
+    def __init__(self):
+        self.buf: dict[str, list] = {}
+
+    def put(self, key, step, t=0.0):
+        self.buf.setdefault(key, []).append(
+            StreamRecord("f", 0, 0, step, np.zeros(4, np.float32),
+                         t_generated=t))
+
+    def stream_keys(self):
+        return list(self.buf)
+
+    def drain(self, key):
+        return self.buf.pop(key, [])
+
+    def pending(self):
+        return sum(len(v) for v in self.buf.values())
+
+
+@pytest.mark.parametrize("clock,n_exec,n_eps,seconds",
+                         [("wall", 128, 8, 1.0), ("virtual", 16, 1, 5.0)])
+def test_idle_executors_block_instead_of_polling(clock, n_exec, n_eps,
+                                                 seconds):
+    """With no traffic an executor wakes only on its safety-net timeout:
+    at most 2 wakeups per executor per second, on either clock, and no
+    executor is woken to steal."""
+    clk = VirtualClock() if clock == "virtual" else None
+    if clk is not None:
+        clk.attach()
+    now = clk.now if clk is not None else time.monotonic
+    t0 = now()
+    eng = StreamEngine([_Feed() for _ in range(n_eps)],
+                       one_stage(lambda k, recs: len(recs)).compile(),
+                       n_exec, trigger_interval=0.25, clock=clk)
+    if clk is not None:
+        clk.sleep(seconds)
+    else:
+        time.sleep(seconds)
+    m = eng.metrics()
+    elapsed = now() - t0
+    eng.drain_and_stop()
+    if clk is not None:
+        clk.detach()
+    assert m["idle_wakeups"] <= 2 * n_exec * elapsed, m["idle_wakeups"]
+    assert m["steal_wakes"] == 0
+    assert not any(e.is_alive() for e in eng.executors)
+
+
+def test_backlog_wakes_an_idle_executor_to_steal():
+    """A sticky stream backs up behind a straggler's slow batch of another
+    stream: the put that leaves the backlog wakes the idle executor, which
+    takes the stream's run and analyses it in sequence order long before
+    the straggler's batch ends (and before its own safety-net timeout)."""
+    clk = VirtualClock()
+    clk.attach()
+    feed = _Feed()
+    eng = StreamEngine([feed], one_stage(
+        lambda k, recs: [r.step for r in recs]).compile(), 2,
+        trigger_interval=60.0, min_batch=1, clock=clk)
+    straggler, thief = eng.executors
+    straggler.slowdown = 5.0
+    feed.put("a", 0)
+    eng.trigger_once()                  # both queues empty: the first wins
+    clk.sleep(0.1)                      # the straggler starts its batch
+    assert straggler.current_key == "a"
+    t_dispatch = clk.now()
+    for step in range(3):               # "b" sticks to the straggler too
+        feed.put("b", step)
+        eng.trigger_once()
+        clk.sleep(0.01)
+    assert clk.wait(lambda: len(eng.collect()) == 4, timeout=20.0)
+    m = eng.metrics()
+    eng.drain_and_stop()
+    clk.detach()
+    assert m["steal_wakes"] > 0 and thief.stolen > 0
+    res = {r.stream_key: [] for r in eng.collect()}
+    for r in sorted(eng.collect(), key=lambda r: r.t_analyzed):
+        res[r.stream_key].append(r)
+    (a,) = res["a"]
+    assert [r.value for r in res["b"]] == [[0], [1], [2]]
+    assert all(r.executor == thief.idx for r in res["b"])
+    assert all(r.t_analyzed < a.t_analyzed for r in res["b"])
+    assert res["b"][-1].t_analyzed - t_dispatch < 0.5
+
+
+@pytest.mark.parametrize("how", ["drain_and_stop", "remove_executor",
+                                 "replace_executor", "kill"])
+def test_shutdown_reaches_blocked_executors(how):
+    """Every way of stopping an executor wakes it from its blocking wait
+    on an empty queue at once, well inside the engine's join timeouts and
+    before the idle safety-net timeout would."""
+    from repro.streaming.engine import _IDLE_WAIT_S
+    clk = VirtualClock()
+    clk.attach()
+    eng = StreamEngine([_Feed()], one_stage(lambda k, recs: 0).compile(),
+                       3, trigger_interval=60.0, clock=clk)
+    clk.sleep(0.1)                      # every executor blocks, idle
+    t0 = clk.now()
+    if how == "remove_executor":
+        stopped = [eng.executors[eng.remove_executor(1)]]
+    elif how == "replace_executor":
+        stopped = [eng.executors[0]]
+        new = eng.replace_executor(0)
+    else:
+        stopped = list(eng.executors)
+        getattr(eng, how)()
+    assert all(clk.join(e, timeout=5.0) for e in stopped)
+    took = clk.now() - t0
+    if how == "replace_executor":
+        assert new.is_alive()
+    eng.drain_and_stop()
+    clk.detach()
+    assert took < _IDLE_WAIT_S / 2, took
+    assert not any(e.is_alive() for e in eng.executors)
+
+
+def test_wakes_and_steals_under_contention_keep_streams_exact():
+    """Stress: more executor threads than cores and a short switch
+    interval, sticky streams backing up behind two stragglers so that
+    dispatches wake idle executors and steals migrate runs all the while.
+    Every record is analysed exactly once, each stream in dispatch order
+    and never twice at a time, and no ordering wait times out."""
+    import os
+    import sys
+    import threading
+    n_exec = 2 * (os.cpu_count() or 4) + 2
+    n_streams, rounds = 32, 20
+    lock = threading.Lock()
+    order: dict[str, list[int]] = {}
+    running: dict[str, int] = {}
+    overlap = []
+
+    def analyze(key, recs):
+        with lock:
+            if running.get(key):
+                overlap.append(key)
+            running[key] = running.get(key, 0) + 1
+        time.sleep(0.001)
+        with lock:
+            running[key] -= 1
+            order.setdefault(key, []).extend(r.step for r in recs)
+        return len(recs)
+
+    feed = _Feed()
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        eng = StreamEngine([feed], one_stage(analyze).compile(), n_exec,
+                           trigger_interval=30.0, min_batch=1)
+        eng.executors[0].slowdown = eng.executors[1].slowdown = 0.02
+        for step in range(rounds):
+            for s in range(n_streams):
+                feed.put(f"s{s}", step)
+            eng.trigger_once()
+            time.sleep(0.005)
+        eng.drain_and_stop(timeout=30)
+    finally:
+        sys.setswitchinterval(switch)
+    m = eng.metrics()
+    assert not overlap, f"concurrent same-stream analysis on {overlap}"
+    assert sorted(order) == sorted(f"s{s}" for s in range(n_streams))
+    for key, steps in order.items():
+        assert steps == list(range(rounds)), f"stream {key}: {steps}"
+    assert m["order_timeouts"] == 0
+    assert m["steal_wakes"] > 0
+    assert not any(e.is_alive() for e in eng.executors)
